@@ -27,11 +27,13 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-# TPU v5e constants (per chip) — single source of truth in the accounting
-# module; re-exported here so existing `from roofline import PEAK_FLOPS`
-# call sites keep working
-from repro.observability.accounting import (HBM_BW, LINK_BW,  # noqa: F401
-                                            PEAK_FLOPS)
+# the dry-run cells are priced at the TPU v5e's published per-chip peaks
+# (the device table in repro.observability.accounting)
+from repro.observability.accounting import PEAKS, V5E  # noqa: E402
+
+PEAK_FLOPS = PEAKS[V5E].flops
+HBM_BW = PEAKS[V5E].hbm_bw
+LINK_BW = PEAKS[V5E].link_bw
 
 RESULTS = os.path.join(os.path.dirname(__file__), "..", "results", "dryrun")
 

@@ -48,5 +48,7 @@ def main() -> None:
           f"{time.time()-t0:.1f}")
 
 
-if __name__ == '__main__':
+if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     main()

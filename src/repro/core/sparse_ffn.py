@@ -98,9 +98,8 @@ def _dense_apply(params, x, scfg: SparsityConfig, gated: bool):
 def _twell_apply(params, x, scfg: SparsityConfig, gated: bool):
     from repro.kernels import ops as kops
     if gated:
-        tw = kops.twell_gate_matmul(x, params["wg"], scfg.twell_tile,
-                                    scfg.twell_c, scfg.activation)
-        y = kops.twell_fused_ffn(x, tw, params["wu"], params["wd"])
+        y, tw = kops.twell_ffn(x, params["wg"], params["wu"], params["wd"],
+                               scfg.twell_tile, scfg.twell_c, scfg.activation)
         # Eq. 2's L1 is over h = h_u * h_g: recover |h| on the pattern via
         # the same gathered h_u elements the fused kernel computes (Eq. 3)
         tc = tw.slot_width
@@ -110,9 +109,9 @@ def _twell_apply(params, x, scfg: SparsityConfig, gated: bool):
         h_abs = jnp.abs(jnp.where(valid, tw.values * hu_p, 0)
                         .astype(jnp.float32))
     else:
-        tw = kops.twell_gate_matmul(x, params["wu"], scfg.twell_tile,
-                                    scfg.twell_c, scfg.activation)
-        y = kops.twell_down_proj(tw, params["wd"])
+        y, tw = kops.twell_ffn_nongated(x, params["wu"], params["wd"],
+                                        scfg.twell_tile, scfg.twell_c,
+                                        scfg.activation)
         h_abs = jnp.abs(tw.values.astype(jnp.float32))
     nnz_rows = tw.nnz.sum(-1)
     aux = {

@@ -180,24 +180,24 @@ def make_cache_specs(cache_shapes: Any, cfg, mesh: Mesh) -> Any:
 # --------------------------------------------------------------------------- #
 
 def current_mesh():
-    """The ambient mesh, or None — ONE resolution path for every caller
-    (training, serving, tests) on every supported jax release.
-
-    Resolution order: the explicit abstract mesh when one is actually set
-    (jax >= 0.5 ``use_mesh``/``set_mesh``), then the thread-local physical
-    mesh that ``with mesh:`` establishes on all releases. The old shim
-    version-forked on the *presence* of ``get_abstract_mesh`` and returned
-    its result unconditionally, so on jax >= 0.5 a ``with mesh:`` context
-    (what 0.4.37 callers — and the serving engine — use) resolved to the
-    empty abstract mesh instead of falling through."""
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get is not None:
-        m = get()
-        if m is not None and getattr(m, "axis_names", ()):
-            return m
-    from jax.interpreters import pxla
-    m = pxla.thread_resources.env.physical_mesh
+    """The ambient mesh that ``jax.set_mesh`` established, or None — one
+    resolution path for every caller (training, serving, tests)."""
+    m = jax.sharding.get_abstract_mesh()
     return None if m.empty else m
+
+
+def model_sharded(fn, in_specs, out_specs):
+    """``fn`` mapped shard-locally over the ambient mesh (``jax.shard_map``),
+    or ``fn`` itself when there is no mesh or its ``model`` axis is 1.
+
+    Pallas calls are opaque to GSPMD, so a kernel under a tensor-parallel
+    mesh is mapped explicitly: ``in_specs``/``out_specs`` name which of its
+    operands split over ``model``; the rest are replicated."""
+    mesh = current_mesh()
+    if mesh is None or tp_size(mesh) <= 1:
+        return fn
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def shard_act(x: jax.Array, *spec) -> jax.Array:

@@ -9,8 +9,10 @@ per-request ``seq_lens``/``num_new`` are scalar-prefetch operands, so the
 kernel walks only the pages a row's live span covers — pages past
 ``seq_len + num_new - 1`` are skipped via ``pl.when``.
 
-Grid: ``(B, Hkv, W)`` — pages innermost, one online-softmax pass per
-(request, kv-head) over that request's live pages. GQA queries ride as a
+Grid: ``(B, W)`` — pages innermost, one online-softmax pass per request
+over its live pages. Each step fetches one whole page ``(bs, Hkv, hd)`` —
+every kv head at once, so the block's last two dims are the pool's own —
+and loops over the kv heads inside the kernel. GQA queries ride as a
 ``G*S`` row axis per kv head (row j ↦ group j // S, chunk offset j % S), so
 no ``repeat_kv`` materialization. Rows in the padded tail
 (``j % S >= num_new``) produce garbage the engine discards (zeros when the
@@ -30,9 +32,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 def _chunk_kernel(bt_ref, sl_ref, nn_ref, q_ref, k_ref, v_ref, o_ref,
-                  m_s, l_s, acc_s, *, bs, width, chunk, scale):
+                  m_s, l_s, acc_s, *, bs, width, chunk, scale, hkv):
     b = pl.program_id(0)
-    page = pl.program_id(2)
+    page = pl.program_id(1)
 
     @pl.when(page == 0)
     def _init():
@@ -49,29 +51,31 @@ def _chunk_kernel(bt_ref, sl_ref, nn_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(live)
     def _compute():
-        q = q_ref[0, 0]                                    # (G*S, hd)
-        k = k_ref[0, :, 0, :]                              # (bs, hd)
-        v = v_ref[0, :, 0, :]
-        s = jnp.dot(q.astype(jnp.float32), k.astype(jnp.float32).T,
-                    preferred_element_type=jnp.float32) * scale
-        # row j is chunk offset j % S of head-group j // S: query position
-        # sl + j % S (full history + causal within the chunk)
-        row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        qpos = sl + row % chunk
-        kpos = page * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(kpos <= qpos, s, -1e30)
-        m_prev = m_s[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_s[...] = l_s[...] * corr + p.sum(axis=1, keepdims=True)
-        acc_s[...] = acc_s[...] * corr + jnp.dot(
-            p, v.astype(jnp.float32), preferred_element_type=jnp.float32)
-        m_s[...] = m_new
+        for h in range(hkv):                # one fetched page, every kv head
+            q = q_ref[0, h].astype(jnp.float32)                # (G*S, hd)
+            k = k_ref[0, :, h, :].astype(jnp.float32)          # (bs, hd)
+            v = v_ref[0, :, h, :].astype(jnp.float32)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale    # (G*S, bs)
+            # row j is chunk offset j % S of head-group j // S: query
+            # position sl + j % S (full history + causal within the chunk)
+            row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+            qpos = sl + row % chunk
+            kpos = page * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = jnp.where(kpos <= qpos, s, -1e30)
+            m_prev = m_s[h]
+            m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            l_s[h] = l_s[h] * corr + p.sum(axis=1, keepdims=True)
+            acc_s[h] = acc_s[h] * corr + jnp.dot(
+                p, v, preferred_element_type=jnp.float32)
+            m_s[h] = m_new
 
     @pl.when(page == width - 1)
     def _finish():
-        o_ref[0, 0] = acc_s[...] / jnp.maximum(l_s[...], 1e-30)
+        o_ref[0] = acc_s[...] / jnp.maximum(l_s[...], 1e-30)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -89,28 +93,28 @@ def paged_chunk_attention_pallas(q, kpool, vpool, block_tables, seq_lens,
     qg = q.reshape(b, s, hkv, g, hd).transpose(0, 2, 3, 1, 4)
     qg = qg.reshape(b, hkv, g * s, hd)
     kernel = functools.partial(_chunk_kernel, bs=bs, width=width,
-                               chunk=s, scale=scale)
+                               chunk=s, scale=scale, hkv=hkv)
 
-    def kv_map(bi, hi, pi, bt_ref, sl_ref, nn_ref):
-        return (bt_ref[bi, pi], 0, hi, 0)
+    def kv_map(bi, pi, bt_ref, sl_ref, nn_ref):
+        return (bt_ref[bi, pi], 0, 0, 0)
 
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(b, hkv, width),
+            grid=(b, width),
             in_specs=[
-                pl.BlockSpec((1, 1, g * s, hd),
-                             lambda bi, hi, pi, bt, sl, nn: (bi, hi, 0, 0)),
-                pl.BlockSpec((1, bs, 1, hd), kv_map),
-                pl.BlockSpec((1, bs, 1, hd), kv_map),
+                pl.BlockSpec((1, hkv, g * s, hd),
+                             lambda bi, pi, bt, sl, nn: (bi, 0, 0, 0)),
+                pl.BlockSpec((1, bs, hkv, hd), kv_map),
+                pl.BlockSpec((1, bs, hkv, hd), kv_map),
             ],
             out_specs=pl.BlockSpec(
-                (1, 1, g * s, hd),
-                lambda bi, hi, pi, bt, sl, nn: (bi, hi, 0, 0)),
-            scratch_shapes=[pltpu.VMEM((g * s, 1), jnp.float32),
-                            pltpu.VMEM((g * s, 1), jnp.float32),
-                            pltpu.VMEM((g * s, hd), jnp.float32)],
+                (1, hkv, g * s, hd),
+                lambda bi, pi, bt, sl, nn: (bi, 0, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((hkv, g * s, 1), jnp.float32),
+                            pltpu.VMEM((hkv, g * s, 1), jnp.float32),
+                            pltpu.VMEM((hkv, g * s, hd), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((b, hkv, g * s, hd), jnp.float32),
         interpret=interpret,

@@ -5,12 +5,14 @@ block table is a scalar-prefetch operand, so each grid step DMAs exactly one
 live page out of the pool — no (B, W*block_size, H, hd) gather and no
 ``repeat_kv`` materialization (GQA is a head-group axis on the query side).
 
-Grid: ``(B, Hkv, num_splits, pages_per_split)`` — pages innermost so the
+Grid: ``(B, num_splits, pages_per_split)`` — pages innermost so the
 online-softmax scratch carries across a split's pages; splits are merged in
-plain jnp afterwards (second-stage reduce). Pages at or past a request's
-live span (``page * block_size > seq_len``) are skipped via ``pl.when``:
-compute per step is proportional to the request's actual ``seq_len``, not
-the padded table width.
+plain jnp afterwards (second-stage reduce). Each step fetches one whole page
+``(bs, Hkv, hd)`` — every kv head at once, so the block's last two dims are
+the pool's own — and loops over the kv heads inside the kernel. Pages at or
+past a request's live span (``page * block_size > seq_len``) are skipped via
+``pl.when``: compute per step is proportional to the request's actual
+``seq_len``, not the padded table width.
 
 Numerics mirror ``kernels.ref.paged_attention_decode``: f32 logits/softmax,
 -1e30 mask, 1/sqrt(hd) scale. The null block (id 0) backs padded batch rows
@@ -30,10 +32,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 def _decode_kernel(bt_ref, sl_ref, q_ref, k_ref, v_ref,
                    o_ref, m_ref, l_ref,
-                   m_s, l_s, acc_s, *, bs, width, pages, scale):
+                   m_s, l_s, acc_s, *, bs, width, pages, scale, hkv):
     b = pl.program_id(0)
-    pi = pl.program_id(3)
-    page = pl.program_id(2) * pages + pi
+    pi = pl.program_id(2)
+    page = pl.program_id(1) * pages + pi
 
     @pl.when(pi == 0)
     def _init():
@@ -46,27 +48,29 @@ def _decode_kernel(bt_ref, sl_ref, q_ref, k_ref, v_ref,
 
     @pl.when(live)
     def _compute():
-        q = q_ref[0, 0]                                    # (G, hd)
-        k = k_ref[0, :, 0, :]                              # (bs, hd)
-        v = v_ref[0, :, 0, :]
-        s = jnp.dot(q.astype(jnp.float32), k.astype(jnp.float32).T,
-                    preferred_element_type=jnp.float32) * scale
-        kpos = page * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(kpos <= sl, s, -1e30)
-        m_prev = m_s[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_s[...] = l_s[...] * corr + p.sum(axis=1, keepdims=True)
-        acc_s[...] = acc_s[...] * corr + jnp.dot(
-            p, v.astype(jnp.float32), preferred_element_type=jnp.float32)
-        m_s[...] = m_new
+        for h in range(hkv):                # one fetched page, every kv head
+            q = q_ref[0, h].astype(jnp.float32)                # (G, hd)
+            k = k_ref[0, :, h, :].astype(jnp.float32)          # (bs, hd)
+            v = v_ref[0, :, h, :].astype(jnp.float32)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale    # (G, bs)
+            kpos = page * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = jnp.where(kpos <= sl, s, -1e30)
+            m_prev = m_s[h]
+            m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            l_s[h] = l_s[h] * corr + p.sum(axis=1, keepdims=True)
+            acc_s[h] = acc_s[h] * corr + jnp.dot(
+                p, v, preferred_element_type=jnp.float32)
+            m_s[h] = m_new
 
     @pl.when(pi == pages - 1)
     def _finish():
-        o_ref[0, 0, 0] = acc_s[...]
-        m_ref[0, 0, 0] = m_s[..., 0]
-        l_ref[0, 0, 0] = l_s[..., 0]
+        o_ref[0, 0] = acc_s[...]
+        m_ref[0, 0] = m_s[...]
+        l_ref[0, 0] = l_s[...]
 
 
 def _pick_splits(width: int, num_splits: int) -> int:
@@ -89,46 +93,45 @@ def paged_decode_attention_pallas(q, kpool, vpool, block_tables, seq_lens,
     # head h = hkv_idx * G + g: reshape matches repeat_kv's group broadcast
     qg = q.reshape(b, hkv, g, hd)
     kernel = functools.partial(_decode_kernel, bs=bs, width=width,
-                               pages=pages, scale=scale)
+                               pages=pages, scale=scale, hkv=hkv)
 
-    def kv_map(bi, hi, si, pi, bt_ref, sl_ref):
+    def kv_map(bi, si, pi, bt_ref, sl_ref):
         page = jnp.minimum(si * pages + pi, width - 1)
-        return (bt_ref[bi, page], 0, hi, 0)
+        return (bt_ref[bi, page], 0, 0, 0)
 
     o, m, l = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(b, hkv, ns, pages),
+            grid=(b, ns, pages),
             in_specs=[
-                pl.BlockSpec((1, 1, g, hd),
-                             lambda bi, hi, si, pi, bt, sl: (bi, hi, 0, 0)),
-                pl.BlockSpec((1, bs, 1, hd), kv_map),
-                pl.BlockSpec((1, bs, 1, hd), kv_map),
+                pl.BlockSpec((1, hkv, g, hd),
+                             lambda bi, si, pi, bt, sl: (bi, 0, 0, 0)),
+                pl.BlockSpec((1, bs, hkv, hd), kv_map),
+                pl.BlockSpec((1, bs, hkv, hd), kv_map),
             ],
             out_specs=[
-                pl.BlockSpec((1, 1, 1, g, hd),
-                             lambda bi, hi, si, pi, bt, sl: (bi, hi, si, 0, 0)),
-                pl.BlockSpec((1, 1, 1, g),
-                             lambda bi, hi, si, pi, bt, sl: (bi, hi, si, 0)),
-                pl.BlockSpec((1, 1, 1, g),
-                             lambda bi, hi, si, pi, bt, sl: (bi, hi, si, 0)),
+                pl.BlockSpec((1, 1, hkv, g, hd),
+                             lambda bi, si, pi, bt, sl: (bi, si, 0, 0, 0)),
+                pl.BlockSpec((1, 1, hkv, g, 1),
+                             lambda bi, si, pi, bt, sl: (bi, si, 0, 0, 0)),
+                pl.BlockSpec((1, 1, hkv, g, 1),
+                             lambda bi, si, pi, bt, sl: (bi, si, 0, 0, 0)),
             ],
-            scratch_shapes=[pltpu.VMEM((g, 1), jnp.float32),
-                            pltpu.VMEM((g, 1), jnp.float32),
-                            pltpu.VMEM((g, hd), jnp.float32)],
+            scratch_shapes=[pltpu.VMEM((hkv, g, 1), jnp.float32),
+                            pltpu.VMEM((hkv, g, 1), jnp.float32),
+                            pltpu.VMEM((hkv, g, hd), jnp.float32)],
         ),
-        out_shape=[jax.ShapeDtypeStruct((b, hkv, ns, g, hd), jnp.float32),
-                   jax.ShapeDtypeStruct((b, hkv, ns, g), jnp.float32),
-                   jax.ShapeDtypeStruct((b, hkv, ns, g), jnp.float32)],
+        out_shape=[jax.ShapeDtypeStruct((b, ns, hkv, g, hd), jnp.float32),
+                   jax.ShapeDtypeStruct((b, ns, hkv, g, 1), jnp.float32),
+                   jax.ShapeDtypeStruct((b, ns, hkv, g, 1), jnp.float32)],
         interpret=interpret,
     )(block_tables, seq_lens, qg, kpool, vpool)
 
     # second-stage reduce: merge per-split partial softmaxes. Dead splits
     # (every page skipped) carry m = -1e30, l = 0 and contribute exactly 0.
-    m_max = m.max(axis=2, keepdims=True)
+    m_max = m.max(axis=1, keepdims=True)
     alpha = jnp.exp(m - m_max)
-    l_tot = (alpha * l).sum(axis=2)
-    out = (alpha[..., None] * o).sum(axis=2) / jnp.maximum(
-        l_tot, 1e-30)[..., None]
+    l_tot = (alpha * l).sum(axis=1)
+    out = (alpha * o).sum(axis=1) / jnp.maximum(l_tot, 1e-30)
     return out.reshape(b, 1, h, hd).astype(q.dtype)

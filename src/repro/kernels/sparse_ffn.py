@@ -3,12 +3,13 @@
 The TPU harvest of the paper's Eq. 3 (DESIGN.md §2): per-(row-block x
 hidden-tile) activity comes free from the TwELL counts; dead tiles skip the
 W_u / W_d MXU work entirely (@pl.when), and h_u is materialized only in VMEM
-(never to HBM) exactly as the CUDA kernel keeps it in registers. On real
-hardware the W DMAs for dead tiles are additionally elided via the
-scalar-prefetch index-map remap (see `_wu_index_map`): dead tiles re-point at
-block 0, which Pallas' double buffering turns into a no-op re-fetch.
+(never to HBM) exactly as the CUDA kernel keeps it in registers. The W DMAs
+of dead tiles still run.
 
-Grid: (M/bm, N/T); full-K blocks (VMEM budget: (bm + 2T) * K * bytes).
+Grid: (M/bm, N/T); full-K blocks (VMEM budget: (bm + 2T) * K * bytes). The
+packed operands arrive in the kernel layout of ``kernels/twell_pack.py``
+(tile-major values/indices, a (bm, N/T) count row block); the jitted
+wrappers take the public ``TwellActs`` layout and convert.
 """
 from __future__ import annotations
 
@@ -18,32 +19,50 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.twell_pack import column, to_tile_major
+
+
+def _unpack_tile(vals_ref, idx_ref, nnz_ref, j, tile: int):
+    """Scatter tile ``j`` of a packed (bm, tc) TwELL block back to a dense
+    (bm, T) f32 block in VMEM, one slot at a time (a slot's column index
+    broadcast against the tile's lane iota); slots at or past the row's count
+    contribute zero."""
+    count = column(nnz_ref, j)                             # (bm, 1) f32
+    local = idx_ref[...] - j * tile                        # (bm, tc)
+    slots = jax.lax.broadcasted_iota(jnp.int32, local.shape, 1)
+    vals = jnp.where(slots.astype(jnp.float32) < count,
+                     vals_ref[...].astype(jnp.float32), 0.0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (local.shape[0], tile), 1)
+    dense = jnp.zeros((local.shape[0], tile), jnp.float32)
+    for s in range(local.shape[1]):
+        dense += jnp.where(cols == local[:, s:s + 1], vals[:, s:s + 1], 0.0)
+    return dense
+
 
 def _kernel(vals_ref, idx_ref, nnz_ref, x_ref, wu_ref, wd_ref, y_ref, *,
-            tile: int, tc: int):
+            tile: int):
     j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
         y_ref[...] = jnp.zeros_like(y_ref)
 
-    active = jnp.max(nnz_ref[...]) > 0
-
-    @pl.when(active)
+    @pl.when(jnp.max(column(nnz_ref, j)) > 0)
     def _compute():
-        # unpack the packed gate tile to a dense (bm, T) block, VMEM-local
-        local = idx_ref[...] - j * tile                    # (bm, tc)
-        slots = jax.lax.broadcasted_iota(jnp.int32, local.shape, 1)
-        valid = slots < nnz_ref[...]                       # (bm, tc)
-        cols = jax.lax.broadcasted_iota(jnp.int32, (1, 1, tile), 2)
-        hit = (local[:, :, None] == cols) & valid[:, :, None]   # (bm, tc, T)
-        g = jnp.sum(jnp.where(hit, vals_ref[...][:, :, None].astype(jnp.float32),
-                              0.0), axis=1)                # (bm, T)
+        g = _unpack_tile(vals_ref, idx_ref, nnz_ref, j, tile)   # (bm, T)
         hu = jnp.dot(x_ref[...], wu_ref[...],
                      preferred_element_type=jnp.float32)   # (bm, T)
         h = (hu * g).astype(x_ref.dtype)
         y_ref[...] += jnp.dot(h, wd_ref[...],
                               preferred_element_type=jnp.float32)
+
+
+def _packed_specs(bm: int, tc: int, nt: int):
+    """BlockSpecs of the kernel-layout TwELL operands: tile-major
+    values/indices (NT, M, tc) and the (bm, NT) count row block."""
+    return [pl.BlockSpec((pl.squeezed, bm, tc), lambda i, j: (j, i, 0)),
+            pl.BlockSpec((pl.squeezed, bm, tc), lambda i, j: (j, i, 0)),
+            pl.BlockSpec((bm, nt), lambda i, j: (i, 0))]
 
 
 @functools.partial(jax.jit, static_argnames=("tile", "bm", "interpret"))
@@ -57,15 +76,11 @@ def twell_fused_ffn_pallas(vals, idx, nnz, x, wu, wd, tile: int = 256,
     tc = vals.shape[1] // nt
     bm = min(bm, m)
     assert m % bm == 0
-    grid = (m // bm, nt)
-    kern = functools.partial(_kernel, tile=tile, tc=tc)
-    y = pl.pallas_call(
+    kern = functools.partial(_kernel, tile=tile)
+    return pl.pallas_call(
         kern,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, tc), lambda i, j: (i, j)),
-            pl.BlockSpec((bm, tc), lambda i, j: (i, j)),
-            pl.BlockSpec((bm, 1), lambda i, j: (i, j)),
+        grid=(m // bm, nt),
+        in_specs=_packed_specs(bm, tc, nt) + [
             pl.BlockSpec((bm, kdim), lambda i, j: (i, 0)),
             pl.BlockSpec((kdim, tile), lambda i, j: (0, j)),
             pl.BlockSpec((tile, kdim), lambda i, j: (j, 0)),
@@ -73,8 +88,7 @@ def twell_fused_ffn_pallas(vals, idx, nnz, x, wu, wd, tile: int = 256,
         out_specs=pl.BlockSpec((bm, kdim), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, kdim), jnp.float32),
         interpret=interpret,
-    )(vals, idx, nnz, x, wu, wd)
-    return y
+    )(to_tile_major(vals, nt), to_tile_major(idx, nt), nnz, x, wu, wd)
 
 
 def _down_kernel(vals_ref, idx_ref, nnz_ref, wd_ref, y_ref, *, tile: int):
@@ -86,17 +100,9 @@ def _down_kernel(vals_ref, idx_ref, nnz_ref, wd_ref, y_ref, *, tile: int):
     def _init():
         y_ref[...] = jnp.zeros_like(y_ref)
 
-    active = jnp.max(nnz_ref[...]) > 0
-
-    @pl.when(active)
+    @pl.when(jnp.max(column(nnz_ref, j)) > 0)
     def _compute():
-        local = idx_ref[...] - j * tile
-        slots = jax.lax.broadcasted_iota(jnp.int32, local.shape, 1)
-        valid = slots < nnz_ref[...]
-        cols = jax.lax.broadcasted_iota(jnp.int32, (1, 1, tile), 2)
-        hit = (local[:, :, None] == cols) & valid[:, :, None]
-        h = jnp.sum(jnp.where(hit, vals_ref[...][:, :, None].astype(jnp.float32),
-                              0.0), axis=1)
+        h = _unpack_tile(vals_ref, idx_ref, nnz_ref, j, tile)
         y_ref[...] += jnp.dot(h.astype(wd_ref.dtype), wd_ref[...],
                               preferred_element_type=jnp.float32)
 
@@ -111,20 +117,16 @@ def twell_down_proj_pallas(vals, idx, nnz, wd, tile: int = 256,
     bm = min(bm, m)
     assert m % bm == 0
     kern = functools.partial(_down_kernel, tile=tile)
-    y = pl.pallas_call(
+    return pl.pallas_call(
         kern,
         grid=(m // bm, nt),
-        in_specs=[
-            pl.BlockSpec((bm, tc), lambda i, j: (i, j)),
-            pl.BlockSpec((bm, tc), lambda i, j: (i, j)),
-            pl.BlockSpec((bm, 1), lambda i, j: (i, j)),
+        in_specs=_packed_specs(bm, tc, nt) + [
             pl.BlockSpec((tile, kdim), lambda i, j: (j, 0)),
         ],
         out_specs=pl.BlockSpec((bm, kdim), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, kdim), jnp.float32),
         interpret=interpret,
-    )(vals, idx, nnz, wd)
-    return y
+    )(to_tile_major(vals, nt), to_tile_major(idx, nt), nnz, wd)
 
 
 def _kernel_gated_dense_gate(x_ref, wg_ref, wu_ref, wd_ref, y_ref, h_ref, *,

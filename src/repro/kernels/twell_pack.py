@@ -2,12 +2,20 @@
 
 The paper's Algorithm 1 adapted to TPU (DESIGN.md §2): the matmul output
 block lives in VMEM; the epilogue replaces the CUDA CTA-scoped atomic counter
-with a branch-free per-row *prefix sum over the lane axis* and a one-hot
-scatter, producing tile-locally packed values / global indices / counts in
-the same kernel — no second pass over dense data, no extra kernel launch.
+with a per-row *prefix count over the lane axis* (a matmul against a
+triangular ones matrix, exact in f32) and a per-slot masked lane reduction,
+producing tile-locally packed values / global indices / counts in the same
+kernel — no second pass over dense data, no extra kernel launch.
 
 Grid: (M/bm, N/T, K/bk), K innermost (TPU sequential minor axis) with a VMEM
 f32 scratch accumulator; the epilogue fires on the last K step.
+
+Kernel layout: values/indices are tile-major ``(N/T, M, T/C)`` so every
+block's last two dims are ``(bm, T/C)`` with ``T/C`` the whole last axis,
+and the counts are one ``(bm, N/T)`` row block per row block, resident
+across the tile axis (each tile writes its column). The jitted wrapper
+converts to and from the public row-major ``TwellActs`` layout
+``(M, N/C)`` / ``(M, N/T)`` (see ``to_tile_major`` / ``from_tile_major``).
 """
 from __future__ import annotations
 
@@ -27,6 +35,26 @@ def _act(name: str, x):
     raise ValueError(name)
 
 
+def to_tile_major(a, nt: int):
+    """(M, NT*tc) row-major TwELL values/indices -> kernel (NT, M, tc)."""
+    m = a.shape[0]
+    return a.reshape(m, nt, -1).transpose(1, 0, 2)
+
+
+def from_tile_major(a):
+    """Kernel (NT, M, tc) -> public row-major (M, NT*tc)."""
+    nt, m, tc = a.shape
+    return a.transpose(1, 0, 2).reshape(m, nt * tc)
+
+
+def column(ref, j):
+    """Column ``j`` of a (rows, cols) ref as a (rows, 1) f32 value — a
+    masked lane reduction, so ``j`` may be a traced grid index."""
+    a = ref[...].astype(jnp.float32)
+    cols = jax.lax.broadcasted_iota(jnp.int32, a.shape, 1)
+    return jnp.sum(jnp.where(cols == j, a, 0.0), axis=1, keepdims=True)
+
+
 def _kernel(x_ref, w_ref, vals_ref, idx_ref, nnz_ref, acc_ref, *,
             tile: int, tc: int, act: str):
     k = pl.program_id(2)
@@ -44,16 +72,30 @@ def _kernel(x_ref, w_ref, vals_ref, idx_ref, nnz_ref, acc_ref, *,
     def _epilogue():
         h = _act(act, acc_ref[...])                       # (bm, T) f32
         mask = h > 0
-        pos = jnp.cumsum(mask.astype(jnp.int32), axis=1) - 1      # (bm, T)
-        slots = jax.lax.broadcasted_iota(jnp.int32, (1, 1, tc), 2)
-        hit = (pos[:, :, None] == slots) & mask[:, :, None]       # (bm, T, tc)
-        vals = jnp.sum(jnp.where(hit, h[:, :, None], 0.0), axis=1)
-        cols = jax.lax.broadcasted_iota(jnp.int32, h.shape + (1,), 1)
-        gidx = jnp.sum(jnp.where(hit, cols + j * tile, 0), axis=1)
-        nnz_ref[...] = jnp.sum(mask.astype(jnp.int32), axis=1,
-                               keepdims=True)
+        # inclusive running count of non-zeros along the tile: mask @ U with
+        # U[t', t] = (t' <= t); 0/1 operands are exact in bf16, sums in f32
+        r = jax.lax.broadcasted_iota(jnp.int32, (tile, tile), 0)
+        c = jax.lax.broadcasted_iota(jnp.int32, (tile, tile), 1)
+        tri = (r <= c).astype(jnp.bfloat16)
+        pos = jnp.dot(mask.astype(jnp.bfloat16), tri,
+                      preferred_element_type=jnp.float32) - 1.0   # (bm, T)
+        cols = (jax.lax.broadcasted_iota(jnp.int32, h.shape, 1)
+                + j * tile).astype(jnp.float32)
+        slots = jax.lax.broadcasted_iota(jnp.int32, (h.shape[0], tc), 1)
+        vals = jnp.zeros((h.shape[0], tc), jnp.float32)
+        gidx = jnp.zeros((h.shape[0], tc), jnp.float32)
+        for s in range(tc):                 # slot s holds the s-th non-zero
+            hit = mask & (pos == s)
+            v = jnp.sum(jnp.where(hit, h, 0.0), axis=1, keepdims=True)
+            g = jnp.sum(jnp.where(hit, cols, 0.0), axis=1, keepdims=True)
+            vals = jnp.where(slots == s, v, vals)
+            gidx = jnp.where(slots == s, g, gidx)
         vals_ref[...] = vals.astype(vals_ref.dtype)
         idx_ref[...] = gidx.astype(jnp.int32)
+        count = jnp.sum(mask.astype(jnp.float32), axis=1, keepdims=True)
+        tiles = jax.lax.broadcasted_iota(jnp.int32, nnz_ref.shape, 1)
+        nnz_ref[...] = jnp.where(tiles == j, count.astype(jnp.int32),
+                                 nnz_ref[...])
 
 
 @functools.partial(jax.jit, static_argnames=("tile", "compression", "act",
@@ -85,16 +127,16 @@ def twell_gate_matmul_pallas(x, w, tile: int = 256, compression: int = 8,
             pl.BlockSpec((bk, tile), lambda i, j, k: (k, j)),
         ],
         out_specs=[
-            pl.BlockSpec((bm, tc), lambda i, j, k: (i, j)),
-            pl.BlockSpec((bm, tc), lambda i, j, k: (i, j)),
-            pl.BlockSpec((bm, 1), lambda i, j, k: (i, j)),
+            pl.BlockSpec((pl.squeezed, bm, tc), lambda i, j, k: (j, i, 0)),
+            pl.BlockSpec((pl.squeezed, bm, tc), lambda i, j, k: (j, i, 0)),
+            pl.BlockSpec((bm, nt), lambda i, j, k: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((m, nt * tc), x.dtype),
-            jax.ShapeDtypeStruct((m, nt * tc), jnp.int32),
+            jax.ShapeDtypeStruct((nt, m, tc), x.dtype),
+            jax.ShapeDtypeStruct((nt, m, tc), jnp.int32),
             jax.ShapeDtypeStruct((m, nt), jnp.int32),
         ],
         scratch_shapes=[pltpu.VMEM((bm, tile), jnp.float32)],
         interpret=interpret,
     )(x, w)
-    return vals, idx, nnz
+    return from_tile_major(vals), from_tile_major(idx), nnz

@@ -72,7 +72,7 @@ def generate(params, cfg, prompt: jax.Array, steps: int, cache_len: int,
     return jnp.concatenate(out, axis=1)
 
 
-def main(argv=None):
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="paper-0.5b")
     ap.add_argument("--reduced", action="store_true")
@@ -81,13 +81,14 @@ def main(argv=None):
     ap.add_argument("--gen", type=int, default=16)
     ap.add_argument("--ffn-impl", default="gather",
                     help="dense | gather (TwELL fused path) | tile_skip")
-    ap.add_argument("--attn-backend", default="ref",
+    ap.add_argument("--attn-backend", default=None,
                     choices=("ref", "pallas", "interpret"),
-                    help="paged-attention read path: ref (gather-pages "
-                         "SDPA, the numerics reference), pallas (fused "
-                         "paged kernels, TPU only), interpret (same "
-                         "kernels via Pallas interpret mode — CPU-safe, "
-                         "slow). Validated against the platform at startup")
+                    help="paged-attention read path (default: pallas on a "
+                         "TPU, ref elsewhere): ref (gather-pages SDPA, the "
+                         "numerics reference), pallas (fused paged kernels, "
+                         "TPU only), interpret (same kernels via Pallas "
+                         "interpret mode — off-TPU only, slow). Validated "
+                         "against the platform at startup")
     ap.add_argument("--block-size", type=int, default=16,
                     help="paged KV-cache block size (tokens)")
     ap.add_argument("--prefill-chunk", type=int, default=64,
@@ -186,14 +187,89 @@ def main(argv=None):
                     help="greedy only: verify the engine reproduces the "
                          "static loop token-for-token (default when "
                          "--reduced)")
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
 
+
+def model_config(args: argparse.Namespace):
+    """The model the flags describe: ``--arch`` (``--reduced``) with the
+    ``--ffn-impl`` stamped in. Weights are random, from ``--seed``."""
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
-    cfg = dataclasses.replace(
+    return dataclasses.replace(
         cfg, sparsity=dataclasses.replace(cfg.sparsity,
                                           ffn_impl=args.ffn_impl))
+
+
+def engine_spec(args: argparse.Namespace, mesh=None):
+    """The ``EngineSpec`` the flags describe — the one place that resolves
+    the serving defaults: telemetry (with request tracing), the pipelined
+    step loop and warmup are on with ``--http`` and off for the one-shot
+    batch demo unless a flag says otherwise."""
+    from repro.serving import EngineSpec, SpecConfig, Telemetry
+    spec = None
+    if args.spec_k:
+        spec = SpecConfig(k=args.spec_k, draft_backend=args.draft_backend,
+                          draft_threshold=args.draft_threshold)
+    use_telemetry = args.http if args.metrics is None else args.metrics
+    if args.trace_out:
+        use_telemetry = True
+    telemetry = Telemetry(trace=bool(args.trace_out) or args.http) \
+        if use_telemetry else False
+    use_pipeline = args.http if args.pipeline is None else args.pipeline
+    if args.disagg:
+        if args.pipeline:
+            raise SystemExit("--disagg runs synchronous engines (KV "
+                             "withdraw cannot race a launched step); drop "
+                             "--pipeline")
+        use_pipeline = False
+        if mesh is not None:
+            raise SystemExit("--disagg requires unsharded KV pools; drop "
+                             "--tp/--mesh")
+    return EngineSpec(
+        backend=args.ffn_impl,
+        attn_backend=args.attn_backend, block_size=args.block_size,
+        max_batch=args.max_batch or args.batch,
+        max_seq_len=args.prompt_len + args.gen, seed=args.seed, spec=spec,
+        prefix_cache=not args.no_prefix_cache,
+        prefill_chunk=args.prefill_chunk, scheduler=args.scheduler,
+        telemetry=telemetry, mesh=mesh, pipeline=use_pipeline)
+
+
+def uses_warmup(args: argparse.Namespace) -> bool:
+    return args.http if args.warmup is None else args.warmup
+
+
+def build_engine(params, cfg, espec, args: argparse.Namespace):
+    from repro.serving import DisaggCoordinator
+    if args.disagg:
+        return DisaggCoordinator(params, cfg, spec=espec,
+                                 transfer_ttl_steps=args.transfer_ttl)
+    return espec.build(params, cfg)
+
+
+def start_http_server(engine, args: argparse.Namespace):
+    """Start ``serve.py --http``'s server over ``engine`` in this process;
+    with warmup, returns once the bucket grid is compiled and /healthz is
+    ready."""
+    from repro.serving.server import ServingServer
+    server = ServingServer(engine, host=args.host, port=args.port,
+                           warmup=uses_warmup(args))
+    server.start()
+    if uses_warmup(args):
+        server.wait_ready()
+        for row in engine.warmup_report:
+            print(f"[serve/warmup] {row['entry']:<8} {row['shape']} "
+                  f"compiled in {row['seconds']:.2f}s", flush=True)
+        print(f"[serve/warmup] {len(engine.warmup_report)} shapes in "
+              f"{engine.warmup_seconds:.2f}s; steady-state serving "
+              f"JIT-compiles nothing", flush=True)
+    return server
+
+
+def main(argv=None):
+    args = parse_args(argv)
+    cfg = model_config(args)
     key = jax.random.PRNGKey(args.seed)
     params = lm.init(key, cfg)
     prompt = jax.random.randint(key, (args.batch, args.prompt_len), 0,
@@ -222,69 +298,23 @@ def main(argv=None):
         return toks
 
     from repro.distributed.sharding import make_serving_mesh
-    from repro.serving import (DisaggCoordinator, EngineSpec, SamplingParams,
-                               SpecConfig, Telemetry, jax_profiler)
-    spec = None
-    if args.spec_k:
-        spec = SpecConfig(k=args.spec_k, draft_backend=args.draft_backend,
-                          draft_threshold=args.draft_threshold)
+    from repro.serving import SamplingParams, jax_profiler
     mesh = None
     if args.tp > 1 or args.mesh:
         mesh = make_serving_mesh(args.tp)
         print(f"[serve/engine] tensor-parallel mesh: tp={args.tp} over "
               f"{[str(d) for d in mesh.devices.flat]}")
-    # telemetry defaults: on when serving HTTP (scrapeable /metrics), off
-    # for the one-shot batch demo; --metrics/--trace-out force it on
-    use_telemetry = args.metrics
-    if use_telemetry is None:
-        use_telemetry = args.http
-    if args.trace_out:
-        use_telemetry = True
-    telemetry = Telemetry(trace=bool(args.trace_out) or args.http) \
-        if use_telemetry else None
-    # pipeline/warmup default on for long-lived HTTP serving (throughput +
-    # no cold-start compiles behind /healthz), off for the one-shot demo
-    use_pipeline = args.http if args.pipeline is None else args.pipeline
-    use_warmup = args.http if args.warmup is None else args.warmup
-    if args.disagg:
-        if args.pipeline:
-            raise SystemExit("--disagg runs synchronous engines (KV "
-                             "withdraw cannot race a launched step); drop "
-                             "--pipeline")
-        use_pipeline = False
-        if mesh is not None:
-            raise SystemExit("--disagg requires unsharded KV pools; drop "
-                             "--tp/--mesh")
-    espec = EngineSpec(
-        backend=args.ffn_impl,
-        attn_backend=args.attn_backend, block_size=args.block_size,
-        max_batch=args.max_batch or args.batch,
-        max_seq_len=args.prompt_len + args.gen, seed=args.seed, spec=spec,
-        prefix_cache=not args.no_prefix_cache,
-        prefill_chunk=args.prefill_chunk, scheduler=args.scheduler,
-        telemetry=telemetry if telemetry is not None else False,
-        mesh=mesh, pipeline=use_pipeline)
-    if args.disagg:
-        engine = DisaggCoordinator(params, cfg, spec=espec,
-                                   transfer_ttl_steps=args.transfer_ttl)
-    else:
-        engine = espec.build(params, cfg)
+    from repro.serving.attention import get_attn_backend
+    espec = engine_spec(args, mesh)
+    engine = build_engine(params, cfg, espec, args)
+    use_telemetry = engine.telemetry is not None
+    spec = espec.spec
+    attn = get_attn_backend(args.attn_backend).name
 
     if args.http:
         import signal
 
-        from repro.serving.server import ServingServer
-        server = ServingServer(engine, host=args.host, port=args.port,
-                               warmup=use_warmup)
-        server.start()
-        if use_warmup:
-            server.wait_ready()
-            for row in engine.warmup_report:
-                print(f"[serve/warmup] {row['entry']:<8} {row['shape']} "
-                      f"compiled in {row['seconds']:.2f}s", flush=True)
-            print(f"[serve/warmup] {len(engine.warmup_report)} shapes in "
-                  f"{engine.warmup_seconds:.2f}s; steady-state serving "
-                  f"JIT-compiles nothing", flush=True)
+        server = start_http_server(engine, args)
         stop = {"flag": False}
 
         def _sig(signum, frame):
@@ -292,7 +322,7 @@ def main(argv=None):
         signal.signal(signal.SIGINT, _sig)
         signal.signal(signal.SIGTERM, _sig)
         print(f"[serve/http] listening on http://{server.host}:{server.port} "
-              f"(backend={args.ffn_impl}, attn={args.attn_backend}, "
+              f"(backend={args.ffn_impl}, attn={attn}, "
               f"scheduler={args.scheduler}, "
               + ("disagg=prefill+decode, " if args.disagg else "") +
               f"tp={args.tp}; POST /v1/completions, GET /healthz"
@@ -315,7 +345,7 @@ def main(argv=None):
     # master key (identical prompts must not produce identical samples)
     sp = SamplingParams(temperature=args.temperature, top_k=args.top_k,
                         top_p=args.top_p)
-    if use_warmup:
+    if uses_warmup(args):
         engine.warmup()
         print(f"[serve/warmup] {len(engine.warmup_report)} shapes "
               f"precompiled in {engine.warmup_seconds:.2f}s")
@@ -331,7 +361,7 @@ def main(argv=None):
     toks = np.concatenate([np.asarray(prompt), gen_toks], axis=1)
     print(f"[serve/engine] generated {toks.shape} in {dt:.2f}s "
           f"({total_new / dt:.1f} tok/s, backend={args.ffn_impl}, "
-          f"attn={args.attn_backend}, block_size={args.block_size}, "
+          f"attn={attn}, block_size={args.block_size}, "
           f"ttft mean {np.mean(ttft) * 1e3:.1f}ms)")
     if engine.prefix_cache and engine.cached_tokens_total:
         print(f"[serve/engine] prefix cache: "
@@ -367,4 +397,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     main()

@@ -35,7 +35,8 @@ from repro.configs import get_config
 from repro.core.sparsity import targeted_reinit
 from repro.data.pipeline import SyntheticLM, make_iterator
 from repro.models import lm
-from repro.observability import RunLogger, SparsityReport, param_count
+from repro.observability import (PEAKS, RunLogger, SparsityReport,
+                                  param_count)
 from repro.optim import adamw
 from repro import training
 
@@ -90,6 +91,9 @@ def main(argv=None):
     ever_active = jnp.zeros((max(cfg.num_layers, 1), cfg.d_ff), bool)
 
     n_params = param_count(params)
+    # MFU only where the device's peak is published (None elsewhere)
+    peaks = PEAKS.get(jax.devices()[0].device_kind)
+    peak_flops = peaks.flops if peaks is not None else None
     tokens_per_step = args.batch * args.seq
     runlog = None
     if args.run_log:
@@ -193,7 +197,7 @@ def main(argv=None):
                 flops_reduction=report.flops_reduction(),
                 step_time_s=dt,
                 tokens_per_s=tokens_per_step / max(dt, 1e-9),
-                mfu=report.mfu_estimate(dt))
+                mfu=report.mfu_estimate(dt, peak_flops))
 
         history.append({"step": step, **metrics})
         if step % args.log_every == 0:
@@ -227,4 +231,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     main()

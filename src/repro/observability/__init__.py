@@ -1,8 +1,8 @@
 """Sparsity & compute observability: per-layer FLOPs/occupancy accounting
 (``accounting``) and structured JSONL run logs (``runlog``). See
 docs/observability.md."""
-from repro.observability.accounting import (CHIP_TDP_W, HBM_BW, LINK_BW,
-                                            PEAK_FLOPS, LayerCost,
+from repro.observability.accounting import (CHIP_TDP_W, PEAKS, V5E,
+                                            DevicePeaks, LayerCost,
                                             SparsityReport,
                                             ffn_bytes_per_token,
                                             ffn_dense_flops_per_token,
@@ -15,7 +15,7 @@ from repro.observability.runlog import (SCHEMA_VERSION, RunLogger,
                                         iter_runlog, read_runlog)
 
 __all__ = [
-    "CHIP_TDP_W", "HBM_BW", "LINK_BW", "PEAK_FLOPS",
+    "CHIP_TDP_W", "PEAKS", "V5E", "DevicePeaks",
     "LayerCost", "SparsityReport",
     "ffn_bytes_per_token", "ffn_dense_flops_per_token",
     "ffn_effective_flops_per_token", "matmul_params", "mfu", "model_flops",

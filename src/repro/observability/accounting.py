@@ -17,8 +17,10 @@ execution backend:
 From those per-layer costs the ``SparsityReport`` derives whole-model
 effective vs dense-equivalent FLOPs per step, bytes moved, an MFU estimate
 (model FLOPs per chip / peak / wall — the same ``MODEL_FLOPS`` convention
-as ``benchmarks/roofline.py``), and a tokens-per-joule proxy. The roofline
-constants live here; ``benchmarks/roofline.py`` imports them.
+as ``benchmarks/roofline.py``), and a tokens-per-joule proxy. The published
+per-chip peaks live here in one table keyed by ``device_kind``;
+``benchmarks/roofline.py`` imports it. A device that is not in the table has
+no peak, so no MFU is reported for it.
 
 Everything is host-side ``float`` math over already-reduced statistics —
 nothing here traces or jits.
@@ -28,10 +30,26 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, List, Optional, Sequence
 
-# ---- roofline constants (TPU v5e, per chip) --------------------------------
-PEAK_FLOPS = 197e12        # bf16 peak matmul throughput [FLOP/s]
-HBM_BW = 819e9             # HBM bandwidth [B/s]
-LINK_BW = 50e9             # ICI bandwidth per link [B/s]
+# ---- published per-chip peaks, keyed by jax ``Device.device_kind`` ---------
+# A device not in the table has no peak: no MFU is reported for it, never
+# another chip's numbers (``PEAKS.get(kind)`` is None).
+
+
+@dataclasses.dataclass(frozen=True)
+class DevicePeaks:
+    flops: float               # bf16 peak matmul throughput [FLOP/s]
+    hbm_bw: float              # HBM bandwidth [B/s]
+    link_bw: float             # ICI bandwidth per link [B/s]
+    source: str
+
+
+V5E = "TPU v5 lite"
+PEAKS: Dict[str, DevicePeaks] = {
+    # 1,600 Gbit/s of ICI per chip over 4 links
+    V5E: DevicePeaks(flops=197e12, hbm_bw=819e9, link_bw=50e9,
+                     source='Google Cloud documentation, "TPU v5e"'),
+}
+
 CHIP_TDP_W = 170.0         # board power envelope [W] — tokens/J *proxy* only
 
 
@@ -61,8 +79,7 @@ def model_flops(cfg, n_params: int, tokens: int, *, train: bool = False
     return float(mult * matmul_params(cfg, n_params) * tokens)
 
 
-def mfu(flops: float, seconds: float, chips: int = 1,
-        peak: float = PEAK_FLOPS) -> float:
+def mfu(flops: float, seconds: float, chips: int, peak: float) -> float:
     """Model-FLOPs utilization: achieved model FLOP/s per chip over peak."""
     if seconds <= 0 or chips <= 0:
         return 0.0
@@ -253,10 +270,11 @@ class SparsityReport:
         return 1.0 - self.ffn_effective_flops / dense
 
     def mfu_estimate(self, step_seconds: float,
-                     peak: float = PEAK_FLOPS) -> Optional[float]:
+                     peak: Optional[float]) -> Optional[float]:
         """MFU from dense-equivalent model FLOPs (the standard convention,
-        so sparsity shows up as *speed*, not as an inflated utilization)."""
-        if self.model_dense_flops is None:
+        so sparsity shows up as *speed*, not as an inflated utilization).
+        None without model FLOPs or without a published peak."""
+        if self.model_dense_flops is None or peak is None:
             return None
         return mfu(self.model_dense_flops, step_seconds, self.chips, peak)
 

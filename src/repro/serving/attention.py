@@ -13,9 +13,12 @@ differ only in how the scattered pools are read:
              the numerics reference every other backend is tested against)
   pallas     fused Pallas kernels (flash-decoding split-K decode kernel +
              chunk-append kernel); block tables consumed in-kernel so only
-             live pages are touched. TPU only.
-  interpret  the same kernels through Pallas interpret mode — runs on CPU,
+             live pages are touched. TPU only, and the TPU default.
+  interpret  the same kernels through Pallas interpret mode — off-TPU only,
              used by CI to pin kernel semantics to the ref backend.
+
+With no backend named, the platform decides: ``pallas`` on a TPU, ``ref``
+elsewhere (``default_attn_backend``).
 
 Under a tensor-parallel mesh the kernel backends run inside ``shard_map``
 over the ``model`` axis (q/pools head-sharded, tables/lens replicated),
@@ -28,9 +31,10 @@ import dataclasses
 from abc import ABC, abstractmethod
 from typing import Dict, Type
 
-import jax
+from jax.sharding import PartitionSpec as P
 
 from repro.config import ModelConfig
+from repro.distributed.sharding import model_sharded
 from repro.kernels import ops
 
 
@@ -45,8 +49,8 @@ class AttentionBackend(ABC):
         return dataclasses.replace(cfg, attn_backend=self.name)
 
     def validate_platform(self, platform: str) -> None:
-        """Reject backend/platform pairs that cannot execute (called once at
-        engine startup, mirroring ``kernels.ops._mode`` dispatch)."""
+        """Reject backend/platform pairs that cannot execute or that would
+        hide the device (called once at engine startup)."""
 
     @abstractmethod
     def forward_decode(self, q, kpool, vpool, block_tables, seq_lens):
@@ -81,29 +85,13 @@ class RefAttentionBackend(AttentionBackend):
 
 
 def _shard_mapped(fn, q, kpool, vpool, *scalars):
-    """Run a paged-attention kernel shard-local over the ``model`` axis.
-
-    Pallas calls are opaque to GSPMD, so unlike the ref path (sharding
-    constraints on einsums) the kernel must be explicitly mapped: q and the
-    pools split on their head axis (dim 2), block tables / seq_lens / num_new
-    replicated. Degrades to a direct call without a mesh or when heads don't
-    divide — same policy as ``sharding.shard_act``."""
-    from repro.distributed.sharding import current_mesh
-    mesh = current_mesh()
-    if mesh is None or "model" not in getattr(mesh, "axis_names", ()):
-        return fn(q, kpool, vpool, *scalars)
-    sizes = dict(zip(mesh.axis_names, mesh.axis_sizes))
-    tp = sizes["model"]
-    if tp <= 1 or q.shape[2] % tp or kpool.shape[2] % tp:
-        return fn(q, kpool, vpool, *scalars)
-    from jax.experimental.shard_map import shard_map
-    P = jax.sharding.PartitionSpec
+    """Run a paged-attention kernel shard-local over the ``model`` axis: q
+    and the pools split on their head axis (dim 2), block tables / seq_lens
+    / num_new replicated. ``validate_mesh`` has already refused head counts
+    the axis does not divide."""
     heads = P(None, None, "model", None)
-    mapped = shard_map(
-        fn, mesh=mesh,
-        in_specs=(heads, heads, heads) + tuple(P() for _ in scalars),
-        out_specs=heads, check_rep=False)
-    return mapped(q, kpool, vpool, *scalars)
+    return model_sharded(fn, (heads, heads, heads) + (P(),) * len(scalars),
+                         heads)(q, kpool, vpool, *scalars)
 
 
 class PallasAttentionBackend(AttentionBackend):
@@ -137,15 +125,19 @@ class PallasAttentionBackend(AttentionBackend):
 
 
 class InterpretAttentionBackend(PallasAttentionBackend):
-    """The Pallas kernels evaluated in interpret mode — platform-agnostic
-    (lowers to plain lax ops), so CPU CI can pin kernel numerics and engine
-    token identity against the ref backend without TPU hardware."""
+    """The Pallas kernels evaluated in interpret mode (lowered to plain lax
+    ops), so CPU CI can pin kernel numerics and engine token identity
+    against the ref backend without TPU hardware. Refused on a TPU, where
+    it would stand in for the compiled kernels."""
 
     name = "interpret"
     kernel_mode = "interpret"
 
     def validate_platform(self, platform: str) -> None:
-        pass
+        if platform == "tpu":
+            raise ValueError(
+                f"attention backend {self.name!r} emulates the kernels off "
+                f"TPU; on a TPU use 'pallas' (the default) or 'ref'")
 
 
 _REGISTRY: Dict[str, Type[AttentionBackend]] = {}
@@ -163,10 +155,19 @@ for _cls in (RefAttentionBackend, PallasAttentionBackend,
 ATTN_BACKENDS = tuple(sorted(_REGISTRY))
 
 
-def get_attn_backend(name_or_backend, **kwargs) -> AttentionBackend:
-    """Resolve an attention backend by name (or pass an instance through)."""
+def default_attn_backend() -> str:
+    """The platform's attention backend: the compiled kernels on a TPU, the
+    plain-JAX reference elsewhere — the FFN kernels' rule."""
+    return ops.kernel_mode()
+
+
+def get_attn_backend(name_or_backend=None, **kwargs) -> AttentionBackend:
+    """Resolve an attention backend by name (None = the platform default),
+    or pass an instance through."""
     if isinstance(name_or_backend, AttentionBackend):
         return name_or_backend
+    if name_or_backend is None:
+        name_or_backend = default_attn_backend()
     try:
         return _REGISTRY[name_or_backend](**kwargs)
     except KeyError:

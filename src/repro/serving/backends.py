@@ -49,6 +49,13 @@ class ServingBackend(ABC):
         return (f"{self.name}: prefill={self.ffn_impl(PREFILL)} "
                 f"decode={self.ffn_impl(DECODE)}")
 
+    def _twell_kernels(self) -> bool:
+        """Does this backend run TwELL-tiled FFN kernels on this platform?
+        Each device then tiles its own d_ff slice."""
+        from repro.kernels.ops import kernel_mode
+        return kernel_mode() == "pallas" and any(
+            self.ffn_impl(mode) != "dense" for mode in (PREFILL, DECODE))
+
     def validate_mesh(self, cfg: ModelConfig, mesh) -> None:
         """Reject model/mesh combinations tensor-parallel serving cannot
         shard. Every backend routes attention through the paged KV pool,
@@ -56,7 +63,9 @@ class ServingBackend(ABC):
         head-sharded wq/wo — so non-divisible head counts would silently
         replicate the very tensors TP exists to split. Fail loudly instead
         (the training path keeps its graceful fallbacks; serving opts into
-        strictness because the operator asked for tp>1 on purpose)."""
+        strictness because the operator asked for tp>1 on purpose). Where
+        the FFN runs as shard-local TwELL kernels, every device's d_ff
+        slice must also be whole tiles, so its tiles are the global ones."""
         from repro.distributed.sharding import tp_size
         tp = tp_size(mesh)
         if tp <= 1:
@@ -64,18 +73,25 @@ class ServingBackend(ABC):
         problems = []
         if cfg.num_kv_heads % tp:
             problems.append(f"num_kv_heads={cfg.num_kv_heads} (paged KV "
-                            f"pool head axis)")
+                            f"pool head axis) not divisible by it")
         if cfg.num_heads % tp:
-            problems.append(f"num_heads={cfg.num_heads} (attention TP)")
+            problems.append(f"num_heads={cfg.num_heads} (attention TP) not "
+                            f"divisible by it")
         if cfg.d_ff % tp:
-            problems.append(f"d_ff={cfg.d_ff} (FFN TP)")
+            problems.append(f"d_ff={cfg.d_ff} (FFN TP) not divisible by it")
+        elif self._twell_kernels() and (cfg.d_ff // tp) % \
+                cfg.sparsity.twell_tile:
+            problems.append(
+                f"local d_ff={cfg.d_ff // tp} not a multiple of "
+                f"twell_tile={cfg.sparsity.twell_tile} (shard-local TwELL "
+                f"kernels)")
         if cfg.padded_vocab % tp:
             problems.append(f"padded_vocab={cfg.padded_vocab} "
-                            f"(vocab-sharded logits)")
+                            f"(vocab-sharded logits) not divisible by it")
         if problems:
             raise ValueError(
-                f"backend {self.name!r} cannot serve under tp={tp}: "
-                + "; ".join(problems) + " not divisible by the model axis")
+                f"backend {self.name!r} cannot serve under tp={tp}, the "
+                f"model axis: " + "; ".join(problems))
 
 
 class DenseBackend(ServingBackend):

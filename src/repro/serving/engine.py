@@ -165,7 +165,7 @@ class ServingEngine:
     """Continuous-batching engine serving one model on one set of weights."""
 
     def __init__(self, params, cfg: ModelConfig, *, backend="dense",
-                 attn_backend="ref",
+                 attn_backend: Optional[str] = None,
                  block_size: int = 16, num_blocks: Optional[int] = None,
                  max_batch: int = 8, max_seq_len: int = 256,
                  min_prefill_bucket: int = 16, seed: int = 0,
@@ -179,9 +179,10 @@ class ServingEngine:
                  role: str = "unified"):
         self.role = role
         self.backend = get_backend(backend)
-        # attention backend first: configure() stamps cfg.attn_backend, and
-        # every derived config below (prefill/decode/draft/verify) must
-        # inherit it so all regimes read the paged KV the same way
+        # attention backend first (None = the platform default): configure()
+        # stamps cfg.attn_backend, and every derived config below
+        # (prefill/decode/draft/verify) must inherit it so all regimes read
+        # the paged KV the same way
         self.attn = get_attn_backend(attn_backend)
         self.attn.validate_platform(jax.default_backend())
         cfg = self.attn.configure(cfg)
@@ -252,9 +253,13 @@ class ServingEngine:
             # arm the sparsity/compute cost model: the decode/prefill entry
             # points collect a per-layer (nnz, tile_frac) probe as extra
             # scan outputs (logits are bit-identical with or without it)
+            device = jax.devices()[0] if mesh is None else \
+                mesh.devices.flat[0]
+            peaks = accounting.PEAKS.get(device.device_kind)
             telemetry.attach_compute(
                 cfg, accounting.param_count(params),
-                chips=1 if mesh is None else mesh.devices.size)
+                chips=1 if mesh is None else mesh.devices.size,
+                peak_flops=None if peaks is None else peaks.flops)
         self._probe = telemetry is not None
         self.prefilling: List[Request] = []
         self.running: List[Request] = []
@@ -313,8 +318,10 @@ class ServingEngine:
 
     def _mesh_ctx(self):
         """Ambient-mesh context for tracing/dispatching jitted serving calls
-        (``shard_act`` resolves the mesh thread-locally); a no-op unsharded."""
-        return self.mesh if self.mesh is not None else contextlib.nullcontext()
+        (``shard_act`` and the shard-local kernels resolve it through
+        ``sharding.current_mesh``); a no-op unsharded."""
+        return jax.set_mesh(self.mesh) if self.mesh is not None \
+            else contextlib.nullcontext()
 
     def _jit_kwargs(self, n_host_args: int, n_rep_outs: int) -> Dict:
         """Explicit in/out_shardings for a serving entrypoint (empty dict
@@ -1442,16 +1449,6 @@ class ServingEngine:
             chunks = bucket_grid(lo, self.prefill_chunk)
             width = self.table_width
 
-            def null_args(padded):
-                # (tables, lens, temps, topks, topps): null tables, zero
-                # valid lengths; temps=1/topp=1 keep the sampling variant's
-                # math well-defined even over the null block's garbage
-                return (np.zeros((padded, width), np.int32),
-                        np.zeros((padded,), np.int32),
-                        np.ones((padded,), np.float32),
-                        np.zeros((padded,), np.int32),
-                        np.ones((padded,), np.float32))
-
             def timed(entry, shape, call):
                 t0 = time.perf_counter()
                 out = call()
@@ -1461,70 +1458,79 @@ class ServingEngine:
                 return out
 
             with self._mesh_ctx():
-                for padded in batches:
-                    _, sl, temps, topks, topps = null_args(padded)
-                    toks = np.zeros((padded, 1), np.int32)
-                    keys = jnp.zeros((padded, 2), jnp.uint32)
-                    # decode runs at a clamped, bucketed table width (see
-                    # _launch_decode) — precompile every width bucket too
-                    for w in bucket_grid(1, width):
-                        bt = np.zeros((padded, w), np.int32)
-                        for greedy in (True, False):
-                            fn = self._jit_decode(padded, w, greedy)
-                            out = timed(
-                                "decode", (padded, w, greedy), lambda: fn(
-                                    self.params, self.kv.pools,
-                                    jnp.asarray(bt), jnp.asarray(sl),
-                                    jnp.asarray(toks), keys,
-                                    jnp.asarray(temps), jnp.asarray(topks),
-                                    jnp.asarray(topps)))
-                            self.kv.swap_pools(out[-1])
-                for padded in batches:
-                    for chunk in chunks:
-                        bt, start, temps, topks, topps = null_args(padded)
-                        ptoks = np.zeros((padded, chunk), np.int32)
-                        num_new = np.zeros((padded,), np.int32)
-                        keys = jnp.zeros((padded, 2), jnp.uint32)
-                        for greedy in (True, False):
-                            fn = self._jit_prefill(padded, chunk, greedy)
-                            out = timed(
-                                "prefill", (padded, chunk, greedy),
-                                lambda: fn(
-                                    self.params, self.kv.pools,
-                                    jnp.asarray(bt), jnp.asarray(ptoks),
-                                    jnp.asarray(start), jnp.asarray(num_new),
-                                    keys, jnp.asarray(temps),
-                                    jnp.asarray(topks), jnp.asarray(topps)))
-                            self.kv.swap_pools(out[-1])
+                # decode runs at a clamped, bucketed table width (see
+                # _launch_decode) — precompile every width bucket too
+                grid = [("decode", padded, w) for padded in batches
+                        for w in bucket_grid(1, width)]
+                grid += [("prefill", padded, chunk) for padded in batches
+                         for chunk in chunks]
+                for entry, padded, size in grid:
+                    args = self._null_step_args(entry, padded, size)
+                    for greedy in (True, False):
+                        fn = self._step_fn(entry, padded, size, greedy)
+                        out = timed(entry, (padded, size, greedy),
+                                    lambda: fn(self.params, self.kv.pools,
+                                               *args))
+                        self.kv.swap_pools(out[-1])
                 if self.spec is not None:
                     k = self.spec.k
                     for padded in batches:
-                        bt, sl0, temps, topks, topps = null_args(padded)
-                        tok0 = np.zeros((padded, 1), np.int32)
-                        dlen = np.zeros((padded,), np.int32)
+                        bt, sl0, tok0, _, temps, topks, topps = \
+                            self._null_step_args("decode", padded, width)
+                        dlen = jnp.zeros((padded,), jnp.int32)
                         dkeys = jnp.zeros((k, padded, 2), jnp.uint32)
                         for greedy in (True, False):
                             out = timed(
                                 "draft", (padded, greedy),
                                 lambda: self.drafter.draft(
-                                    self.params, self.kv.pools,
-                                    jnp.asarray(bt), jnp.asarray(sl0),
-                                    jnp.asarray(tok0), jnp.asarray(dlen),
-                                    dkeys, jnp.asarray(temps),
-                                    jnp.asarray(topks), jnp.asarray(topps),
+                                    self.params, self.kv.pools, bt, sl0,
+                                    tok0, dlen, dkeys, temps, topks, topps,
                                     greedy=greedy))
                             self.kv.swap_pools(out[-1])
-                        vtoks = np.zeros((padded, k + 1), np.int32)
-                        num_new = np.zeros((padded,), np.int32)
+                        vtoks = jnp.zeros((padded, k + 1), jnp.int32)
                         out = timed(
                             "verify", (padded,),
                             lambda: self.verifier.verify(
-                                self.params, self.kv.pools, jnp.asarray(bt),
-                                jnp.asarray(sl0), jnp.asarray(num_new),
-                                jnp.asarray(vtoks)))
+                                self.params, self.kv.pools, bt, sl0, dlen,
+                                vtoks))
                         self.kv.swap_pools(out[-1])
             self.warmup_seconds = time.perf_counter() - t_start
             self.warmup_report = report
             if self.telemetry is not None:
                 self.telemetry.on_warmup(self.warmup_seconds, len(report))
             return report
+
+    def _step_fn(self, entry: str, padded: int, size: int, greedy: bool):
+        """The jitted ``decode`` (``size`` = table width) or ``prefill``
+        (``size`` = chunk) step program of one bucket."""
+        if entry == "decode":
+            return self._jit_decode(padded, size, greedy)
+        return self._jit_prefill(padded, size, greedy)
+
+    def _null_step_args(self, entry: str, padded: int, size: int) -> tuple:
+        """Host args of one bucket's step (after params and pools) over
+        all-null block tables with zero valid lengths — the shape/trace
+        every padded production row already exercises, so no allocator or
+        request state is touched and the writes all land in the discarded
+        null block. temps=1/topp=1 keep the sampling variant's math
+        well-defined even over the null block's garbage."""
+        ints = functools.partial(jnp.zeros, dtype=jnp.int32)
+        sampling = (jnp.zeros((padded, 2), jnp.uint32),
+                    jnp.ones((padded,), jnp.float32), ints((padded,)),
+                    jnp.ones((padded,), jnp.float32))
+        if entry == "decode":      # (bt, sl, toks, keys, temps, topks, topps)
+            return (ints((padded, size)), ints((padded,)),
+                    ints((padded, 1))) + sampling
+        # (bt, toks, start, num_new, keys, temps, topks, topps)
+        return (ints((padded, self.table_width)), ints((padded, size)),
+                ints((padded,)), ints((padded,))) + sampling
+
+    def lower_step(self, entry: str, padded: int, size: int,
+                   greedy: bool = True):
+        """``jax.stages.Lowered`` of one bucket's ``decode``/``prefill``
+        step program, as ``warmup`` compiles it — for inspecting what the
+        device runs (``.compile().as_text()``)."""
+        with self._lock, self._mesh_ctx():
+            fn = self._step_fn(entry, padded, size, greedy)
+            return fn.lower(self.params, self.kv.pools,
+                            *self._null_step_args(entry, padded, size))

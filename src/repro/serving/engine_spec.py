@@ -29,7 +29,7 @@ class EngineSpec:
     instance would corrupt both engines' queues."""
 
     backend: str = "dense"
-    attn_backend: str = "ref"
+    attn_backend: Optional[str] = None     # None = the platform default
     block_size: int = 16
     num_blocks: Optional[int] = None
     max_batch: int = 8

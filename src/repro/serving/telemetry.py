@@ -403,7 +403,8 @@ class ServingMetrics:
         self.mfu = r.gauge(
             "serving_mfu",
             "Live MFU estimate: dense-equivalent FLOPs of the last step "
-            "over wall time x chips x peak")
+            "over wall time x chips x the device's published peak (unset "
+            "on a device without one)")
         self.tokens_per_joule = r.gauge(
             "serving_tokens_per_joule_proxy",
             "Committed tokens per joule at chip TDP (an energy proxy, not "
@@ -553,12 +554,14 @@ class Telemetry:
 
     # ---- sparsity / compute accounting -------------------------------------
 
-    def attach_compute(self, cfg, n_params: int, chips: int = 1) -> None:
+    def attach_compute(self, cfg, n_params: int, chips: int = 1,
+                       peak_flops: Optional[float] = None) -> None:
         """Arm the sparsity/compute cost model. The engine calls this once
         at build time when sparsity probing is enabled; ``on_ffn`` and the
-        MFU/energy gauges stay inert until it does."""
+        MFU/energy gauges stay inert until it does. ``peak_flops`` is the
+        device's published per-chip peak; without one no MFU is set."""
         self._compute = {"cfg": cfg, "n_params": int(n_params),
-                         "chips": int(chips)}
+                         "chips": int(chips), "peak_flops": peak_flops}
 
     def on_ffn(self, tokens: int, nnz_per_layer, tile_frac_per_layer=None,
                ffn_present=None, impl: Optional[str] = None) -> None:
@@ -622,7 +625,10 @@ class Telemetry:
             self._kv_prev[event] = occ[key]
         if self._compute is not None:
             chips = self._compute["chips"]
-            m.mfu.set(accounting.mfu(self._win_flops, wall_s, chips))
+            peak = self._compute["peak_flops"]
+            if peak is not None:
+                m.mfu.set(accounting.mfu(self._win_flops, wall_s, chips,
+                                         peak))
             m.tokens_per_joule.set(accounting.tokens_per_joule(
                 self._win_tokens, wall_s, chips))
             self._win_flops, self._win_tokens = 0.0, 0
@@ -663,7 +669,8 @@ class Telemetry:
                 "effective_flops_total": eff,
                 "dense_flops_total": dense,
                 "flops_reduction": 1.0 - eff / dense if dense else None,
-                "mfu": m.mfu.value(),
+                "mfu": m.mfu.value()
+                if self._compute["peak_flops"] is not None else None,
                 "tokens_per_joule_proxy": m.tokens_per_joule.value(),
             }
         return {

@@ -1,17 +1,12 @@
 """Multi-device integration tests (8 host CPU devices via subprocess, so the
-main pytest process keeps its single-device view)."""
-import json
+main pytest process keeps its single-device view). The subprocess harness
+is CPU-only by construction: it pins JAX_PLATFORMS=cpu and forces the host
+platform's device count."""
 import os
 import subprocess
 import sys
 
-import jax.sharding
 import pytest
-
-if not hasattr(jax.sharding, "AxisType"):
-    pytest.skip("distributed tests exercise jax>=0.6 explicit sharding "
-                "(jax.sharding.AxisType / jax.set_mesh), unavailable on the "
-                "installed jax", allow_module_level=True)
 
 ROOT = os.path.join(os.path.dirname(__file__), "..")
 
@@ -19,6 +14,7 @@ ROOT = os.path.join(os.path.dirname(__file__), "..")
 def _run(script: str, timeout=560) -> str:
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"        # CPU-only: never the chip
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
     r = subprocess.run([sys.executable, "-c", script], capture_output=True,
                        text=True, timeout=timeout, env=env)

@@ -116,9 +116,11 @@ class TestRoofline:
 
     def test_constants_shared_with_accounting(self):
         from repro.observability import accounting
-        assert roofline.PEAK_FLOPS == accounting.PEAK_FLOPS
-        assert roofline.HBM_BW == accounting.HBM_BW
-        assert roofline.LINK_BW == accounting.LINK_BW
+        v5e = accounting.PEAKS["TPU v5 lite"]
+        assert roofline.PEAK_FLOPS == v5e.flops == 197e12
+        assert roofline.HBM_BW == v5e.hbm_bw == 819e9
+        assert roofline.LINK_BW == v5e.link_bw
+        assert "cpu" not in accounting.PEAKS
 
     def test_model_flops_convention(self):
         from repro.configs import get_config

@@ -192,9 +192,22 @@ def test_pallas_backend_requires_tpu():
         get_attn_backend("pallas").validate_platform("cpu")
     get_attn_backend("interpret").validate_platform("cpu")
     get_attn_backend("ref").validate_platform("cpu")
+    get_attn_backend("ref").validate_platform("tpu")
+    with pytest.raises(ValueError, match="emulates the kernels off TPU"):
+        get_attn_backend("interpret").validate_platform("tpu")
     if jax.default_backend() != "tpu":
         with pytest.raises(ValueError, match="requires TPU"):
             _engine(_tiny_model()[0], _tiny_cfg(), attn_backend="pallas")
+
+
+def test_default_attn_backend_follows_platform(monkeypatch):
+    """No backend named: the compiled kernels on a TPU, the reference
+    elsewhere — the engine never quietly serves a TPU through ``ref``."""
+    from repro.serving import attention
+    eng = _engine(_tiny_model()[0], _tiny_cfg())
+    assert eng.attn.name == attention.default_attn_backend() == "ref"
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: "tpu")
+    assert get_attn_backend(None).name == "pallas"
 
 
 # --------------------------------------------------------------------------- #

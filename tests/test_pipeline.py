@@ -391,6 +391,10 @@ def test_warmup_zero_steady_state_compiles(dense_model):
     engine.generate(prompts, max_tokens=6)
     assert dict(tm.summary()["jit_compiles"]) == snap, \
         "steady-state serving JIT-compiled after warmup"
+    # lower_step inspects the very programs warmup built, adding none
+    for entry, size in (("decode", engine.table_width), ("prefill", 8)):
+        assert engine.lower_step(entry, 2, size).compile().as_text()
+    assert dict(tm.summary()["jit_compiles"]) == snap
     _assert_clean(engine)
 
 
@@ -471,6 +475,7 @@ print('TP_PIPELINE_OK')
 def test_tp2_pipeline_token_identity():
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"        # CPU-only: never the chip
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
     r = subprocess.run([sys.executable, "-c", _TP_SCRIPT],
                        capture_output=True, text=True, timeout=560, env=env)

@@ -1,9 +1,10 @@
 """Tensor-parallel serving tests.
 
 Multi-device cases run on 8 fake CPU host devices in a subprocess (so the
-main pytest process keeps its single-device view), with the plain
-``with mesh:`` context — no jax>=0.6 explicit-sharding APIs — so this file
-runs on the pinned jax 0.4.37 unlike tests/test_distributed.py.
+main pytest process keeps its single-device view). The subprocess harness
+is CPU-only by construction (it pins JAX_PLATFORMS=cpu and forces the host
+platform's device count); the four-chip path is ``chip_smoke.py --tp 4``,
+never these tests.
 
 The acceptance bar: greedy outputs must be token-identical between the
 unsharded engine and tp=2/4, for all three FFN backends, with speculative
@@ -30,6 +31,7 @@ ROOT = os.path.join(os.path.dirname(__file__), "..")
 def _run(script: str, timeout=560) -> str:
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"        # CPU-only: never the chip
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
     r = subprocess.run([sys.executable, "-c", script], capture_output=True,
                        text=True, timeout=timeout, env=env)
@@ -47,7 +49,7 @@ def test_paged_pool_spec_shards_kv_heads_only():
     on the mesh (the generic batch-dim rule must not touch dim 1)."""
     cfg = get_config("paper-0.5b").reduced()
     shape = (cfg.num_layers, 16, 4, cfg.num_kv_heads, cfg.resolved_head_dim)
-    mesh = AbstractMesh((("data", 2), ("model", 2)))
+    mesh = AbstractMesh((2, 2), ("data", "model"))
     for name in ("kpool", "vpool"):
         spec = cache_spec(name, shape, cfg, mesh)
         assert tuple(spec) == (None, None, None, "model", None), (name, spec)
@@ -68,12 +70,11 @@ def test_make_paged_pool_shardings_specs():
 
 
 def test_current_mesh_one_path_with_and_without_context():
-    """The unified resolver sees a ``with mesh:`` context on this jax
-    version (and returns None outside any context) — training and serving
-    now share this single code path."""
+    """The resolver sees a ``jax.set_mesh`` context (and returns None
+    outside any context) — training and serving share this one path."""
     assert current_mesh() is None
     mesh = Mesh(np.asarray(jax.devices()[:1]), ("model",))
-    with mesh:
+    with jax.set_mesh(mesh):
         got = current_mesh()
         assert got is not None and "model" in got.axis_names
     assert current_mesh() is None
@@ -82,11 +83,33 @@ def test_current_mesh_one_path_with_and_without_context():
 @pytest.mark.parametrize("backend", ["dense", "gather", "tile_skip"])
 def test_validate_mesh_rejects_nondivisible_heads(backend):
     cfg = get_config("paper-0.5b").reduced()   # 4 heads / 4 kv heads
-    bad = AbstractMesh((("model", 3),))
+    bad = AbstractMesh((3,), ("model",))
     with pytest.raises(ValueError, match="not divisible"):
         get_backend(backend).validate_mesh(cfg, bad)
-    ok = AbstractMesh((("model", 2),))
+    ok = AbstractMesh((2,), ("model",))
     get_backend(backend).validate_mesh(cfg, ok)   # no raise
+
+
+@pytest.mark.parametrize("backend", ["gather", "tile_skip"])
+def test_validate_mesh_refuses_local_dff_off_tile(backend, monkeypatch):
+    """Where the FFN runs as shard-local TwELL kernels (a TPU), each
+    device's d_ff slice must be whole tiles: paper-0.5b's d_ff 5632 at tp=4
+    leaves 1408 per device, which tile 256 does not divide but tile 128
+    does. The dense backend runs no TwELL kernel and is unaffected."""
+    import dataclasses
+
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "kernel_mode", lambda: "pallas")
+    cfg = get_config("paper-0.5b")
+    tp4 = AbstractMesh((4,), ("model",))
+    with pytest.raises(ValueError, match="twell_tile=256"):
+        get_backend(backend).validate_mesh(cfg, tp4)
+    get_backend("dense").validate_mesh(cfg, tp4)
+    cfg128 = dataclasses.replace(cfg, sparsity=dataclasses.replace(
+        cfg.sparsity, twell_tile=128))
+    get_backend(backend).validate_mesh(cfg128, tp4)
+    monkeypatch.setattr(ops, "kernel_mode", lambda: "ref")
+    get_backend(backend).validate_mesh(cfg, tp4)   # GSPMD tiles globally
 
 
 # --------------------------------------------------------------------------- #
