@@ -81,5 +81,6 @@ def flash_attention_pallas(q, k, v, bq: int = 512, bk: int = 512,
                         pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, hd), jnp.float32)],
         interpret=interpret,
+        name="flash_attention",
     )(qf, kf, vf)
     return o.reshape(b, h, s, hd).transpose(0, 2, 1, 3)

@@ -69,6 +69,7 @@ def hybrid_to_dense_pallas(ell_vals, ell_idx, row_nnz, is_sparse, w,
         out_specs=pl.BlockSpec((bm, kdim), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, kdim), jnp.float32),
         interpret=interpret,
+        name="hybrid_to_dense",
     )(ell_vals, ell_idx, row_nnz[:, None], is_sparse[:, None], w)
     return y
 
@@ -123,5 +124,6 @@ def dense_to_hybrid_pallas(x, w, ell_idx, row_nnz, is_sparse,
         out_specs=pl.BlockSpec((bm, e), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, e), jnp.float32),
         interpret=interpret,
+        name="dense_to_hybrid",
     )(x, w, ell_idx, row_nnz[:, None], is_sparse[:, None])
     return vals

@@ -118,6 +118,7 @@ def paged_chunk_attention_pallas(q, kpool, vpool, block_tables, seq_lens,
         ),
         out_shape=jax.ShapeDtypeStruct((b, hkv, g * s, hd), jnp.float32),
         interpret=interpret,
+        name="paged_chunk_attention",
     )(block_tables, seq_lens, num_new, qg, kpool, vpool)
 
     out = out.reshape(b, hkv, g, s, hd).transpose(0, 3, 1, 2, 4)

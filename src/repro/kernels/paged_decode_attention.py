@@ -126,6 +126,7 @@ def paged_decode_attention_pallas(q, kpool, vpool, block_tables, seq_lens,
                    jax.ShapeDtypeStruct((b, ns, hkv, g, 1), jnp.float32),
                    jax.ShapeDtypeStruct((b, ns, hkv, g, 1), jnp.float32)],
         interpret=interpret,
+        name="paged_decode_attention",
     )(block_tables, seq_lens, qg, kpool, vpool)
 
     # second-stage reduce: merge per-split partial softmaxes. Dead splits

@@ -88,6 +88,7 @@ def twell_fused_ffn_pallas(vals, idx, nnz, x, wu, wd, tile: int = 256,
         out_specs=pl.BlockSpec((bm, kdim), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, kdim), jnp.float32),
         interpret=interpret,
+        name="twell_fused_ffn",
     )(to_tile_major(vals, nt), to_tile_major(idx, nt), nnz, x, wu, wd)
 
 
@@ -126,6 +127,7 @@ def twell_down_proj_pallas(vals, idx, nnz, wd, tile: int = 256,
         out_specs=pl.BlockSpec((bm, kdim), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, kdim), jnp.float32),
         interpret=interpret,
+        name="twell_down_proj",
     )(to_tile_major(vals, nt), to_tile_major(idx, nt), nnz, wd)
 
 
@@ -182,5 +184,6 @@ def tile_skip_ffn_pallas(x, wg, wu, wd, tile: int = 256, act: str = "relu",
         out_shape=[jax.ShapeDtypeStruct((m, kdim), jnp.float32),
                    jax.ShapeDtypeStruct((m, n), x.dtype)],
         interpret=interpret,
+        name="tile_skip_ffn",
     )(x, wg, wu, wd)
     return y, h

@@ -138,5 +138,6 @@ def twell_gate_matmul_pallas(x, w, tile: int = 256, compression: int = 8,
         ],
         scratch_shapes=[pltpu.VMEM((bm, tile), jnp.float32)],
         interpret=interpret,
+        name="twell_gate_matmul",
     )(x, w)
     return from_tile_major(vals), from_tile_major(idx), nnz

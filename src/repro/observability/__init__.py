@@ -1,25 +1,22 @@
 """Sparsity & compute observability: per-layer FLOPs/occupancy accounting
 (``accounting``) and structured JSONL run logs (``runlog``). See
 docs/observability.md."""
-from repro.observability.accounting import (CHIP_TDP_W, PEAKS, V5E,
-                                            DevicePeaks, LayerCost,
-                                            SparsityReport,
+from repro.observability.accounting import (PEAKS, V5E, DevicePeaks,
+                                            LayerCost, SparsityReport,
                                             ffn_bytes_per_token,
                                             ffn_dense_flops_per_token,
                                             ffn_effective_flops_per_token,
                                             matmul_params, mfu, model_flops,
                                             param_count, stats_from_hidden,
-                                            tile_occupancy_from_twell,
-                                            tokens_per_joule)
+                                            tile_occupancy_from_twell)
 from repro.observability.runlog import (SCHEMA_VERSION, RunLogger,
                                         iter_runlog, read_runlog)
 
 __all__ = [
-    "CHIP_TDP_W", "PEAKS", "V5E", "DevicePeaks",
+    "PEAKS", "V5E", "DevicePeaks",
     "LayerCost", "SparsityReport",
     "ffn_bytes_per_token", "ffn_dense_flops_per_token",
     "ffn_effective_flops_per_token", "matmul_params", "mfu", "model_flops",
     "param_count", "stats_from_hidden", "tile_occupancy_from_twell",
-    "tokens_per_joule",
     "SCHEMA_VERSION", "RunLogger", "iter_runlog", "read_runlog",
 ]

@@ -17,7 +17,7 @@ execution backend:
 From those per-layer costs the ``SparsityReport`` derives whole-model
 effective vs dense-equivalent FLOPs per step, bytes moved, an MFU estimate
 (model FLOPs per chip / peak / wall — the same ``MODEL_FLOPS`` convention
-as ``benchmarks/roofline.py``), and a tokens-per-joule proxy. The published
+as ``benchmarks/roofline.py``). The published
 per-chip peaks live here in one table keyed by ``device_kind``;
 ``benchmarks/roofline.py`` imports it. A device that is not in the table has
 no peak, so no MFU is reported for it.
@@ -50,9 +50,6 @@ PEAKS: Dict[str, DevicePeaks] = {
                      source='Google Cloud documentation, "TPU v5e"'),
 }
 
-CHIP_TDP_W = 170.0         # board power envelope [W] — tokens/J *proxy* only
-
-
 def param_count(params) -> int:
     """Total parameter count of a params pytree."""
     import jax
@@ -84,15 +81,6 @@ def mfu(flops: float, seconds: float, chips: int, peak: float) -> float:
     if seconds <= 0 or chips <= 0:
         return 0.0
     return flops / (seconds * chips * peak)
-
-
-def tokens_per_joule(tokens: float, seconds: float, chips: int = 1,
-                     tdp_w: float = CHIP_TDP_W) -> float:
-    """Energy-efficiency *proxy*: tokens over (wall x chip TDP). Not a power
-    measurement — a fixed-envelope normalization so runs are comparable."""
-    if seconds <= 0:
-        return 0.0
-    return tokens / (seconds * chips * tdp_w)
 
 
 # ---- per-layer FFN cost model ----------------------------------------------

@@ -70,8 +70,12 @@ inter-token latency histograms, and JIT compile-event counts into a
 ``telemetry.MetricsRegistry`` (Prometheus text via ``GET /metrics`` on the
 HTTP server), and records per-request lifecycle spans + a whole-engine
 step timeline exportable as Chrome-trace JSON (``engine.export_trace``).
-Telemetry off (the default) is a few ``is None`` checks per step — the
-token stream is identical either way. See docs/observability.md.
+Each step phase is also a ``jax.profiler`` annotation (``engine.<phase>``
+inside an ``engine.step`` step marker), and the step programs are named
+``decode_step`` / ``prefill_step``, so a profiler trace shows host phases
+and device programs on one clock. Telemetry off (the default) is a few
+``is None`` checks per step — the token stream is identical either way. See
+docs/observability.md.
 """
 from __future__ import annotations
 
@@ -109,10 +113,12 @@ from repro.serving.spec import (Drafter, SpecConfig, Verifier,
                                 rollback_after_verify)
 from repro.serving.telemetry import (PHASE_ADMISSION, PHASE_CANCEL,
                                      PHASE_COLLECT, PHASE_DECODE,
-                                     PHASE_DRAFT, PHASE_LAUNCH,
-                                     PHASE_OVERLAP, PHASE_PLAN,
+                                     PHASE_DRAFT, PHASE_LAUNCH, PHASE_PLAN,
                                      PHASE_PREFILL, PHASE_SAMPLE,
                                      PHASE_VERIFY, Telemetry)
+
+_UNTIMED = contextlib.nullcontext()      # the span of an engine without
+#                                          telemetry
 
 
 @dataclasses.dataclass(frozen=True)
@@ -248,18 +254,14 @@ class ServingEngine:
                 spec_k=str(0 if spec is None else spec.k),
                 tp=str(1 if mesh is None else mesh.devices.size))
             if spec is not None:
-                self.drafter.on_compile = telemetry.on_compile
-                self.verifier.on_compile = telemetry.on_compile
+                self.drafter.dispatch = self._dispatch
+                self.verifier.dispatch = self._dispatch
             # arm the sparsity/compute cost model: the decode/prefill entry
             # points collect a per-layer (nnz, tile_frac) probe as extra
             # scan outputs (logits are bit-identical with or without it)
-            device = jax.devices()[0] if mesh is None else \
-                mesh.devices.flat[0]
-            peaks = accounting.PEAKS.get(device.device_kind)
             telemetry.attach_compute(
                 cfg, accounting.param_count(params),
-                chips=1 if mesh is None else mesh.devices.size,
-                peak_flops=None if peaks is None else peaks.flops)
+                chips=1 if mesh is None else mesh.devices.size)
         self._probe = telemetry is not None
         self.prefilling: List[Request] = []
         self.running: List[Request] = []
@@ -298,6 +300,7 @@ class ServingEngine:
         self._handles: Dict[int, RequestHandle] = {}
         self._decode_fns: Dict[int, callable] = {}
         self._prefill_fns: Dict[int, callable] = {}
+        self._dispatched: set = set()    # step programs run at least once
         # pipelined step loop (plan/launch/collect; see pipeline.py):
         # pipeline=False keeps the original synchronous step as the
         # numerics/latency reference — token streams are identical either way
@@ -343,6 +346,23 @@ class ServingEngine:
     def _wake(self) -> None:
         if self.on_new_work is not None:
             self.on_new_work()
+
+    def _span(self, phase: str):
+        """Context manager timing one phase of the current step (a no-op
+        without telemetry)."""
+        if self.telemetry is None:
+            return _UNTIMED
+        return self.telemetry.span(phase, self._step_idx)
+
+    def _dispatch(self, entry: str, fn, *args):
+        """Call the jitted step program ``fn``. Its first call compiles it:
+        with telemetry that call is counted and runs inside the
+        ``compile.<entry>`` span, so a step that recompiles shows."""
+        if self.telemetry is None or fn in self._dispatched:
+            return fn(*args)
+        self._dispatched.add(fn)
+        with self.telemetry.compiling(entry, self._step_idx):
+            return fn(*args)
 
     # ------------------------------------------------------------------ API
 
@@ -565,56 +585,46 @@ class ServingEngine:
         (resolved by the NEXT step, or by ``flush()``). Per-request token
         streams are identical in both modes."""
         with self._lock:
-            if self.pipeline:
-                return self._step_pipelined()
-            return self._step_sync()
+            step_span = _UNTIMED if self.telemetry is None else \
+                self.telemetry.step_span(self._step_idx)
+            with step_span:
+                if self.pipeline:
+                    return self._step_pipelined()
+                return self._step_sync()
 
     def _step_sync(self) -> List[StepEvent]:
         """The original fully synchronous step: each phase launches AND
         collects before the next phase plans (the numerics/latency
         reference for the pipelined loop)."""
-        tm = self.telemetry
         t_step = time.perf_counter()
         self._sync_s = 0.0
         events: List[StepEvent] = []
-        events += self._process_cancels()
-        if tm is not None:
-            tm.phase(PHASE_CANCEL, t_step, time.perf_counter(),
-                     self._step_idx)
+        with self._span(PHASE_CANCEL):
+            events += self._process_cancels()
         decode_batch = padded = 0
         spec_batch = drafted = accepted = 0
         if self.running:
             spec_rows = [r for r in self.running if self._can_spec(r)]
             normal_rows = [r for r in self.running if not self._can_spec(r)]
             if normal_rows:
-                t0 = time.perf_counter()
-                dl = self._launch_decode(normal_rows)
-                decode_batch, padded = dl.batch, dl.padded
-                events.extend(self._collect_decode(dl))
-                if tm is not None:
-                    tm.phase(PHASE_DECODE, t0, time.perf_counter(),
-                             self._step_idx)
+                with self._span(PHASE_DECODE):
+                    dl = self._launch_decode(normal_rows)
+                    decode_batch, padded = dl.batch, dl.padded
+                    events.extend(self._collect_decode(dl))
             if spec_rows:
                 # draft / verify / sample sub-phases are timed inside
                 sl = self._launch_spec(spec_rows, timed=True)
-                spec_batch, drafted, accepted, evs = \
-                    self._collect_spec(sl, timed=True)
+                spec_batch, drafted, accepted, evs = self._collect_spec(sl)
                 events.extend(evs)
-        t0 = time.perf_counter()
-        admitted, cached_toks, evs = self._admit()
+        with self._span(PHASE_ADMISSION):
+            admitted, cached_toks, evs = self._admit()
         events.extend(evs)
-        if tm is not None:
-            tm.phase(PHASE_ADMISSION, t0, time.perf_counter(),
-                     self._step_idx)
-        t0 = time.perf_counter()
         pf_tokens = 0
-        pl = self._launch_prefill()
-        if pl is not None:
-            pf_tokens = sum(pl.chunk_lens)
-            events.extend(self._collect_prefill(pl))
-            if tm is not None and pf_tokens:
-                tm.phase(PHASE_PREFILL, t0, time.perf_counter(),
-                         self._step_idx)
+        if self.prefilling:
+            with self._span(PHASE_PREFILL):
+                pl = self._launch_prefill()
+                pf_tokens = sum(pl.chunk_lens)
+                events.extend(self._collect_prefill(pl))
         return self._finalize_step(
             events, t_step=t_step, decode_batch=decode_batch, padded=padded,
             admitted=admitted, cached_toks=cached_toks, pf_tokens=pf_tokens,
@@ -643,17 +653,14 @@ class ServingEngine:
         events: List[StepEvent] = []
         inflight = self._inflight
         # ---- plan: pure host work against committed state
-        events += self._process_cancels(defer_inflight=inflight is not None)
-        t0 = time.perf_counter()
-        if tm is not None:
-            tm.phase(PHASE_CANCEL, t_step, t0, self._step_idx)
-        admitted, cached_toks, evs = self._admit(
-            defer_preempt=inflight is not None)
+        with self._span(PHASE_PLAN):
+            with self._span(PHASE_CANCEL):
+                events += self._process_cancels(
+                    defer_inflight=inflight is not None)
+            with self._span(PHASE_ADMISSION):
+                admitted, cached_toks, evs = self._admit(
+                    defer_preempt=inflight is not None)
         events.extend(evs)
-        t_plan_end = time.perf_counter()
-        if tm is not None:
-            tm.phase(PHASE_ADMISSION, t0, t_plan_end, self._step_idx)
-            tm.phase(PHASE_PLAN, t_step, t_plan_end, self._step_idx)
         # ---- collect: resolve the previous launch, commit its tokens
         overlap_ms = 0.0
         spec_batch = drafted = accepted = 0
@@ -662,41 +669,36 @@ class ServingEngine:
             t_collect0 = time.perf_counter()
             overlap_ms = (t_collect0 - inflight.t_launched) * 1e3
             if tm is not None:
-                tm.phase(PHASE_OVERLAP, inflight.t_launched, t_collect0,
-                         self._step_idx)
-            if inflight.decode is not None:
-                events.extend(self._collect_decode(inflight.decode))
-            if inflight.spec is not None:
-                spec_batch, drafted, accepted, evs = self._collect_spec(
-                    inflight.spec, timed=False)
-                events.extend(evs)
-            if inflight.prefill is not None:
-                events.extend(self._collect_prefill(inflight.prefill))
-            events.extend(self._flush_pending_preempts())
-            if tm is not None:
-                tm.phase(PHASE_COLLECT, t_collect0, time.perf_counter(),
-                         self._step_idx)
+                tm.overlap(inflight.t_launched, t_collect0, self._step_idx)
+            with self._span(PHASE_COLLECT):
+                if inflight.decode is not None:
+                    events.extend(self._collect_decode(inflight.decode))
+                if inflight.spec is not None:
+                    spec_batch, drafted, accepted, evs = self._collect_spec(
+                        inflight.spec)
+                    events.extend(evs)
+                if inflight.prefill is not None:
+                    events.extend(self._collect_prefill(inflight.prefill))
+                events.extend(self._flush_pending_preempts())
         # ---- launch: dispatch on post-collect state; nothing blocks
-        t_launch0 = time.perf_counter()
         decode_batch = padded = pf_tokens = 0
         dl = sl = None
-        if self.running:
-            spec_rows = [r for r in self.running if self._can_spec(r)]
-            normal_rows = [r for r in self.running if not self._can_spec(r)]
-            if normal_rows:
-                dl = self._launch_decode(normal_rows)
-                decode_batch, padded = dl.batch, dl.padded
-            if spec_rows:
-                sl = self._launch_spec(spec_rows, timed=False)
-        pl = self._launch_prefill()
-        if pl is not None:
-            pf_tokens = sum(pl.chunk_lens)
-        if dl is not None or sl is not None or pl is not None:
-            self._inflight = InFlightStep(decode=dl, spec=sl, prefill=pl,
-                                          t_launched=time.perf_counter())
-        if tm is not None:
-            tm.phase(PHASE_LAUNCH, t_launch0, time.perf_counter(),
-                     self._step_idx)
+        with self._span(PHASE_LAUNCH):
+            if self.running:
+                spec_rows = [r for r in self.running if self._can_spec(r)]
+                normal_rows = [r for r in self.running
+                               if not self._can_spec(r)]
+                if normal_rows:
+                    dl = self._launch_decode(normal_rows)
+                    decode_batch, padded = dl.batch, dl.padded
+                if spec_rows:
+                    sl = self._launch_spec(spec_rows, timed=False)
+            pl = self._launch_prefill()
+            if pl is not None:
+                pf_tokens = sum(pl.chunk_lens)
+            if dl is not None or sl is not None or pl is not None:
+                self._inflight = InFlightStep(decode=dl, spec=sl, prefill=pl,
+                                              t_launched=time.perf_counter())
         return self._finalize_step(
             events, t_step=t_step, decode_batch=decode_batch, padded=padded,
             admitted=admitted, cached_toks=cached_toks, pf_tokens=pf_tokens,
@@ -769,7 +771,7 @@ class ServingEngine:
             if inflight.decode is not None:
                 events.extend(self._collect_decode(inflight.decode))
             if inflight.spec is not None:
-                _, _, _, evs = self._collect_spec(inflight.spec, timed=False)
+                _, _, _, evs = self._collect_spec(inflight.spec)
                 events.extend(evs)
             if inflight.prefill is not None:
                 events.extend(self._collect_prefill(inflight.prefill))
@@ -810,8 +812,6 @@ class ServingEngine:
         would silently re-specialize on a new bt shape without going
         through here, bypassing the jit_compiles counter."""
         if (padded_batch, width, greedy) not in self._decode_fns:
-            if self.telemetry is not None:
-                self.telemetry.on_compile("decode")
             cfg = self.cfg_decode
             probe = self._probe
 
@@ -820,7 +820,8 @@ class ServingEngine:
             # outputs and never feeds back into the logits
             @functools.partial(jax.jit, donate_argnums=(1,),
                                **self._jit_kwargs(7, 3 if probe else 2))
-            def fn(params, pools, bt, sl, toks, keys, temps, topks, topps):
+            def decode_step(params, pools, bt, sl, toks, keys, temps, topks,
+                            topps):
                 out = lm.paged_decode_step(params, pools, bt, sl, toks, cfg,
                                            collect_aux=probe)
                 logits, aux, pools = out if probe else (out[0], None, out[1])
@@ -832,15 +833,13 @@ class ServingEngine:
                                                topps)
                 return (tok, last, aux, pools) if probe else \
                     (tok, last, pools)
-            self._decode_fns[(padded_batch, width, greedy)] = fn
+            self._decode_fns[(padded_batch, width, greedy)] = decode_step
         return self._decode_fns[(padded_batch, width, greedy)]
 
     def _jit_prefill(self, padded_batch: int, padded_chunk: int,
                      greedy: bool):
         key = (padded_batch, padded_chunk, greedy)
         if key not in self._prefill_fns:
-            if self.telemetry is not None:
-                self.telemetry.on_compile("prefill")
             cfg = self.cfg_prefill
             probe = self._probe
 
@@ -848,8 +847,8 @@ class ServingEngine:
             # (tok, last[, ffn_aux]) out
             @functools.partial(jax.jit, donate_argnums=(1,),
                                **self._jit_kwargs(8, 3 if probe else 2))
-            def fn(params, pools, bt, toks, start, num_new, keys, temps,
-                   topks, topps):
+            def prefill_step(params, pools, bt, toks, start, num_new, keys,
+                             temps, topks, topps):
                 # last_only: the head runs on each row's final valid hidden
                 # state only — never (B, C, V) over the whole chunk
                 out = lm.paged_prefill(params, pools, bt, toks, num_new, cfg,
@@ -862,7 +861,7 @@ class ServingEngine:
                                                topps)
                 return (tok, last, aux, pools) if probe else \
                     (tok, last, pools)
-            self._prefill_fns[key] = fn
+            self._prefill_fns[key] = prefill_step
         return self._prefill_fns[key]
 
     def _finish(self, req: Request, reason: str) -> RequestOutput:
@@ -1024,9 +1023,9 @@ class ServingEngine:
             keys = keys.at[:b].set(sampling_mod.batch_keys(base, pos))
         with self._mesh_ctx():
             fn = self._jit_decode(padded, width, all_greedy)
-            out = fn(
-                self.params, self.kv.pools, jnp.asarray(bt), jnp.asarray(sl),
-                jnp.asarray(toks), keys, jnp.asarray(temps),
+            out = self._dispatch(
+                "decode", fn, self.params, self.kv.pools, jnp.asarray(bt),
+                jnp.asarray(sl), jnp.asarray(toks), keys, jnp.asarray(temps),
                 jnp.asarray(topks), jnp.asarray(topps))
             if self._probe:
                 next_toks, logits, ffn_aux, pools = out
@@ -1072,8 +1071,8 @@ class ServingEngine:
         DEVICE from the draft output, so both calls go out back-to-back
         with no host readback between them — in pipelined mode
         (``timed=False``) nothing here blocks at all; the synchronous path
-        (``timed=True``) keeps its draft/verify phase timing by syncing the
-        draft output before dispatching verify."""
+        (``timed=True``) times the draft and verify phases by syncing each
+        call's output inside its span."""
         b = len(rows)
         k = self.spec.k
         padded = _bucket(b, 1, self.max_batch)
@@ -1113,34 +1112,33 @@ class ServingEngine:
                 sampling_mod.spec_batch_keys(base, pos + j,
                                              sampling_mod.STREAM_DRAFT)
                 for j in range(k)]))
-        tm = self.telemetry
-        t0 = time.perf_counter()
-        with self._mesh_ctx():
-            d_toks, d_logits, pools = self.drafter.draft(
-                self.params, self.kv.pools, jnp.asarray(bt),
-                jnp.asarray(sl0), jnp.asarray(tok0), jnp.asarray(dlen), keys,
-                jnp.asarray(temps), jnp.asarray(topks), jnp.asarray(topps),
-                greedy=all_greedy)
-            self.kv.swap_pools(pools)
-        if timed:
-            self._sync(d_toks)
-            if tm is not None:
-                tm.phase(PHASE_DRAFT, t0, time.perf_counter(),
-                         self._step_idx)
+        with self._span(PHASE_DRAFT) if timed else _UNTIMED:
+            with self._mesh_ctx():
+                d_toks, d_logits, pools = self.drafter.draft(
+                    self.params, self.kv.pools, jnp.asarray(bt),
+                    jnp.asarray(sl0), jnp.asarray(tok0), jnp.asarray(dlen),
+                    keys, jnp.asarray(temps), jnp.asarray(topks),
+                    jnp.asarray(topps), greedy=all_greedy)
+                self.kv.swap_pools(pools)
+            if timed:
+                self._sync(d_toks)
         num_new = dlen + (dlen > 0)            # k_eff + 1; 0 for padded rows
-        t_verify0 = time.perf_counter()
-        with self._mesh_ctx():
-            tok0_dev = jnp.asarray(tok0)
-            if self.mesh is not None:
-                # commit the host column to the replicated layout d_toks
-                # already has, so the eager concat never guesses a sharding
-                tok0_dev = jax.device_put(
-                    tok0_dev, sharding.replicated(self.mesh))
-            verify_toks = jnp.concatenate([tok0_dev, d_toks], axis=1)
-            t_logits, pools = self.verifier.verify(
-                self.params, self.kv.pools, jnp.asarray(bt), jnp.asarray(sl0),
-                jnp.asarray(num_new), verify_toks)
-            self.kv.swap_pools(pools)
+        with self._span(PHASE_VERIFY) if timed else _UNTIMED:
+            with self._mesh_ctx():
+                tok0_dev = jnp.asarray(tok0)
+                if self.mesh is not None:
+                    # commit the host column to the replicated layout d_toks
+                    # already has, so the eager concat never guesses a
+                    # sharding
+                    tok0_dev = jax.device_put(
+                        tok0_dev, sharding.replicated(self.mesh))
+                verify_toks = jnp.concatenate([tok0_dev, d_toks], axis=1)
+                t_logits, pools = self.verifier.verify(
+                    self.params, self.kv.pools, jnp.asarray(bt),
+                    jnp.asarray(sl0), jnp.asarray(num_new), verify_toks)
+                self.kv.swap_pools(pools)
+            if timed:
+                self._sync(t_logits)
         start_host_copy(d_toks)
         start_host_copy(t_logits)
         if not all_greedy:
@@ -1148,66 +1146,62 @@ class ServingEngine:
         return SpecLaunch(rows=list(rows), batch=b, padded=padded,
                           k_effs=k_effs, all_greedy=all_greedy,
                           d_toks=d_toks, d_logits=d_logits,
-                          t_logits=t_logits, t_verify0=t_verify0)
+                          t_logits=t_logits)
 
-    def _collect_spec(self, sl: SpecLaunch, *, timed: bool):
+    def _collect_spec(self, sl: SpecLaunch):
         """Resolve a launched draft+verify pair: accept on the host, commit
         the accepted prefix + correction/bonus token per row (>= 1 token
         guaranteed), roll the block-table tail covering rejected scratch
         positions back to the pool, and settle deferred cancels."""
         tm = self.telemetry
         self._sync(sl.d_toks, sl.t_logits)
-        if timed and tm is not None:
-            tm.phase(PHASE_VERIFY, sl.t_verify0, time.perf_counter(),
-                     self._step_idx)
         d_toks = np.asarray(sl.d_toks)
         t_logits = np.asarray(sl.t_logits)
         d_logits_np = None if sl.all_greedy else np.asarray(sl.d_logits)
         events: List[StepEvent] = []
         drafted_total = accepted_total = 0
-        t_sample = time.perf_counter()
-        for i, r in enumerate(sl.rows):
-            k_eff = sl.k_effs[i]
-            emitted, n_acc = self.verifier.accept(
-                r, k_eff, d_toks[i, :k_eff],
-                None if d_logits_np is None else d_logits_np[i, :k_eff],
-                t_logits[i, :k_eff + 1])
-            r.spec_drafted += k_eff
-            r.spec_accepted += n_acc
-            drafted_total += k_eff
-            accepted_total += n_acc
-            reason = None
-            committed = []
-            for j, tok in enumerate(emitted):
-                if r.logits_trace is not None:
-                    r.logits_trace.append(t_logits[i, j].astype(np.float32))
-                committed.append(int(tok))
-                reason = r.append(int(tok))
+        # host-side acceptance / rejection-sampling over the whole batch
+        with self._span(PHASE_SAMPLE):
+            for i, r in enumerate(sl.rows):
+                k_eff = sl.k_effs[i]
+                emitted, n_acc = self.verifier.accept(
+                    r, k_eff, d_toks[i, :k_eff],
+                    None if d_logits_np is None else d_logits_np[i, :k_eff],
+                    t_logits[i, :k_eff + 1])
+                r.spec_drafted += k_eff
+                r.spec_accepted += n_acc
+                drafted_total += k_eff
+                accepted_total += n_acc
+                reason = None
+                committed = []
+                for j, tok in enumerate(emitted):
+                    if r.logits_trace is not None:
+                        r.logits_trace.append(
+                            t_logits[i, j].astype(np.float32))
+                    committed.append(int(tok))
+                    reason = r.append(int(tok))
+                    if reason:
+                        break
+                if tm is not None:
+                    tm.on_spec(r, k_eff, n_acc)
+                    tm.on_tokens(r, len(committed))
+                events.append(StepEvent(kind=EVENT_TOKEN, rid=r.rid,
+                                        step=self._step_idx,
+                                        tokens=tuple(committed)))
                 if reason:
-                    break
-            if tm is not None:
-                tm.on_spec(r, k_eff, n_acc)
-                tm.on_tokens(r, len(committed))
-            events.append(StepEvent(kind=EVENT_TOKEN, rid=r.rid,
-                                    step=self._step_idx,
-                                    tokens=tuple(committed)))
-            if reason:
-                events.append(self._terminal_event(r, reason))
-            else:
-                cancel_ev = self._deferred_cancel(r)
-                if cancel_ev is not None:
-                    # _finish freed the whole table, scratch tail included
-                    events.append(cancel_ev)
-                    continue
-                # rollback: blocks past the committed length (seq_len - 1
-                # cached slots) return to the pool and the reservation
-                freed = rollback_after_verify(self.kv, r.rid, r.seq_len - 1)
-                r.reserved_blocks += freed
-                self._reserved += freed
-        if tm is not None:
-            # host-side acceptance / rejection-sampling over the whole batch
-            tm.phase(PHASE_SAMPLE, t_sample, time.perf_counter(),
-                     self._step_idx)
+                    events.append(self._terminal_event(r, reason))
+                else:
+                    cancel_ev = self._deferred_cancel(r)
+                    if cancel_ev is not None:
+                        # _finish freed the whole table, scratch tail included
+                        events.append(cancel_ev)
+                        continue
+                    # rollback: blocks past the committed length (seq_len - 1
+                    # cached slots) return to the pool and the reservation
+                    freed = rollback_after_verify(self.kv, r.rid,
+                                                  r.seq_len - 1)
+                    r.reserved_blocks += freed
+                    self._reserved += freed
         return sl.batch, drafted_total, accepted_total, events
 
     def _admit(self, defer_preempt: bool = False):
@@ -1367,8 +1361,8 @@ class ServingEngine:
             keys = keys.at[:b].set(sampling_mod.batch_keys(base, pos))
         with self._mesh_ctx():
             fn = self._jit_prefill(padded_b, padded_c, all_greedy)
-            out = fn(
-                self.params, self.kv.pools, jnp.asarray(bt),
+            out = self._dispatch(
+                "prefill", fn, self.params, self.kv.pools, jnp.asarray(bt),
                 jnp.asarray(toks), jnp.asarray(start), jnp.asarray(num_new),
                 keys, jnp.asarray(temps), jnp.asarray(topks),
                 jnp.asarray(topps))
@@ -1469,8 +1463,9 @@ class ServingEngine:
                     for greedy in (True, False):
                         fn = self._step_fn(entry, padded, size, greedy)
                         out = timed(entry, (padded, size, greedy),
-                                    lambda: fn(self.params, self.kv.pools,
-                                               *args))
+                                    lambda: self._dispatch(
+                                        entry, fn, self.params,
+                                        self.kv.pools, *args))
                         self.kv.swap_pools(out[-1])
                 if self.spec is not None:
                     k = self.spec.k

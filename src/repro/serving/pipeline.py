@@ -86,7 +86,6 @@ class SpecLaunch:
     d_toks: Any                      # device (padded, k) int32
     d_logits: Any                    # device (padded, k, V); unused if greedy
     t_logits: Any                    # device (padded, k+1, V) float32
-    t_verify0: float                 # perf_counter at verify dispatch
 
 
 @dataclasses.dataclass

@@ -65,6 +65,9 @@ class Request:
     # ---- engine-managed state ----------------------------------------------
     status: str = WAITING
     output_tokens: List[int] = dataclasses.field(default_factory=list)
+    token_times: List[float] = dataclasses.field(default_factory=list)
+    #                                          perf_counter commit time of
+    #                                          each output token
     base_key: Optional[jax.Array] = None     # per-request PRNG base key
     logits_trace: Optional[list] = None      # per-token logits (debug mode)
     reserved_blocks: int = 0                 # growth blocks admission promised
@@ -99,6 +102,9 @@ class Request:
             raise ValueError(f"max_tokens must be >= 1, got {self.max_tokens}")
         self.sampling.validate()
         self.prompt = [int(t) for t in self.prompt]
+        # pre-committed outputs (a resume on another engine) count as
+        # committed on arrival here
+        self.token_times = [self.arrival_time] * len(self.output_tokens)
 
     @property
     def seq_len(self) -> int:
@@ -114,9 +120,13 @@ class Request:
         return self.status in (FINISHED, CANCELLED)
 
     def append(self, token: int, now: Optional[float] = None) -> Optional[str]:
-        """Record one generated token; returns a finish reason or None."""
+        """Record one generated token, committed at ``now`` (perf_counter;
+        default: the call's time); returns a finish reason or None."""
+        t = time.perf_counter() if now is None else now
         if self.first_token_time is None:
-            self.first_token_time = time.perf_counter() if now is None else now
+            self.first_token_time = t
+        # the time first: a reader that sees the token sees its time too
+        self.token_times.append(t)
         self.output_tokens.append(int(token))
         if self.eos_token_id is not None and int(token) == self.eos_token_id:
             return FINISH_EOS
@@ -231,6 +241,7 @@ class RequestHandle:
     engine thread appends tokens, handler threads read deltas):
 
       ``new_tokens()``   tokens committed since the last call (delta cursor)
+      ``new_tokens_timed()``  the same, and its first token's commit time
       ``tokens``         all committed output tokens so far
       ``status``         waiting | prefilling | running | preempted |
                          finished | cancelled
@@ -281,6 +292,13 @@ class RequestHandle:
         delta = out[self._cursor:len(out)]
         self._cursor += len(delta)
         return [int(t) for t in delta]
+
+    def new_tokens_timed(self) -> Tuple[List[int], Optional[float]]:
+        """``new_tokens()`` and the engine's commit time (perf_counter) of
+        the delta's earliest token; None for an empty delta."""
+        first = self._cursor
+        delta = self.new_tokens()
+        return delta, (self._req.token_times[first] if delta else None)
 
     @property
     def num_preemptions(self) -> int:

@@ -34,17 +34,31 @@ Endpoints:
 
 The repo has no tokenizer: prompts are token-id lists, and completions
 return ``token_ids`` (an OpenAI-shaped envelope, not a drop-in clone).
+
+With engine telemetry on, the front door times itself on each request's
+trace track: ``http.receive`` from the handler's entry until ``submit``
+returned, and one ``http.write`` per SSE chunk with its ``tokens`` and its
+``lag_ms``, from the engine's commit of the chunk's earliest token to the
+flush. Both are also ``jax.profiler`` annotations of the same names.
 """
 from __future__ import annotations
 
+import contextlib
 import json
 import select
 import socket
 import threading
+import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Optional
 
+from jax.profiler import TraceAnnotation
+
 from repro.serving.sampling import SamplingParams
+
+HTTP_RECEIVE = "http.receive"
+HTTP_WRITE = "http.write"
+_UNTRACED = contextlib.nullcontext()
 
 
 def _completion_chunk(rid: int, tokens, finish_reason: Optional[str]):
@@ -72,6 +86,7 @@ class ServingServer:
         if not self._warmup:
             self._ready.set()
         engine.on_new_work = self._work.set
+        self._telemetry = engine.telemetry
         server = self
 
         class Handler(BaseHTTPRequestHandler):
@@ -116,16 +131,35 @@ class ServingServer:
                 self.end_headers()
                 self.wfile.write(body)
 
-            def do_POST(self):
+            def _body(self) -> Optional[dict]:
+                """The request's JSON body; None after a 400 reply."""
                 try:
                     n = int(self.headers.get("Content-Length", 0))
-                    body = json.loads(self.rfile.read(n) or b"{}")
+                    return json.loads(self.rfile.read(n) or b"{}")
                 except (ValueError, json.JSONDecodeError) as e:
                     self._json(400, {"error": f"bad JSON: {e}"})
-                    return
+                    return None
+
+            def do_POST(self):
                 if self.path == "/v1/completions":
-                    self._completions(body)
-                elif self.path == "/v1/cancel":
+                    t_receive = time.perf_counter()
+                    with server.annotation(HTTP_RECEIVE):
+                        body = self._body()
+                        handle = None if body is None else \
+                            self._submit(body)
+                    if handle is None:
+                        return
+                    server.record(handle.rid, HTTP_RECEIVE, t_receive,
+                                  time.perf_counter())
+                    if body.get("stream"):
+                        self._stream(handle)
+                    else:
+                        self._reply(handle)
+                    return
+                body = self._body()
+                if body is None:
+                    return
+                if self.path == "/v1/cancel":
                     rid = str(body.get("id", "")).replace("cmpl-", "")
                     ok = rid.lstrip("-").isdigit() and \
                         server.engine.cancel(int(rid))
@@ -133,14 +167,16 @@ class ServingServer:
                 else:
                     self._json(404, {"error": f"no route {self.path}"})
 
-            def _completions(self, body: dict) -> None:
+            def _submit(self, body: dict):
+                """Submit a completion request; its handle, or None after a
+                400 reply."""
                 prompt = body.get("prompt")
                 if not isinstance(prompt, list) or not prompt or \
                         not all(isinstance(t, int) for t in prompt):
                     self._json(400, {"error": "prompt must be a non-empty "
                                               "list of token ids (the repo "
                                               "ships no tokenizer)"})
-                    return
+                    return None
                 try:
                     seed = body.get("seed")
                     sp = SamplingParams(
@@ -152,17 +188,16 @@ class ServingServer:
                     # callers that drain handle.events(); the SSE loop
                     # below reads new_tokens() deltas, so don't buffer
                     eos = body.get("eos_token_id")
-                    handle = server.engine.submit(
+                    return server.engine.submit(
                         prompt, sampling=sp,
                         max_tokens=int(body.get("max_tokens", 16)),
                         eos_token_id=None if eos is None else int(eos),
                         priority=int(body.get("priority", 0)))
                 except (TypeError, ValueError) as e:
                     self._json(400, {"error": str(e)})
-                    return
-                if body.get("stream"):
-                    self._stream(handle)
-                    return
+                    return None
+
+            def _reply(self, handle) -> None:
                 server.wait_finished(handle)
                 if not handle.finished:          # shutdown raced the request
                     self._json(503, {"error": "server shutting down"})
@@ -189,6 +224,18 @@ class ServingServer:
                 except OSError:
                     return True
 
+            def _write(self, rid: int, data: bytes, tokens: int,
+                       t_commit: float) -> None:
+                """Write and flush one SSE chunk of ``tokens`` tokens, the
+                earliest committed at ``t_commit``."""
+                t0 = time.perf_counter()
+                with server.annotation(HTTP_WRITE):
+                    self.wfile.write(data)
+                    self.wfile.flush()
+                t1 = time.perf_counter()
+                server.record(rid, HTTP_WRITE, t0, t1, tokens=tokens,
+                              lag_ms=(t1 - t_commit) * 1e3)
+
             def _stream(self, handle) -> None:
                 self.send_response(200)
                 self.send_header("Content-Type", "text/event-stream")
@@ -205,18 +252,20 @@ class ServingServer:
                         # opposite order could drop the final tokens when
                         # the engine finishes the request between the reads
                         done = handle.finished
-                        delta = handle.new_tokens()
+                        delta, t_commit = handle.new_tokens_timed()
                         if delta or done:
                             chunk = _completion_chunk(
                                 handle.rid, delta,
                                 handle.finish_reason if done else None)
-                            self.wfile.write(
-                                b"data: " + json.dumps(chunk).encode()
-                                + b"\n\n")
-                            self.wfile.flush()
+                            data = b"data: " + json.dumps(chunk).encode() \
+                                + b"\n\n"
+                            if done:
+                                data += b"data: [DONE]\n\n"
+                                if t_commit is None:   # only the close left
+                                    t_commit = handle.result().finish_time
+                            self._write(handle.rid, data, len(delta),
+                                        t_commit)
                         if done:
-                            self.wfile.write(b"data: [DONE]\n\n")
-                            self.wfile.flush()
                             return
                         if server._stop.is_set():
                             return       # shutdown: drop the stream mid-way
@@ -230,6 +279,19 @@ class ServingServer:
         self.httpd.daemon_threads = True
         self.host, self.port = self.httpd.server_address[:2]
         self._threads = []
+
+    # ---- front-door tracing ------------------------------------------------
+
+    def annotation(self, name: str):
+        """The ``name`` profiler annotation (a no-op without telemetry)."""
+        return _UNTRACED if self._telemetry is None else TraceAnnotation(name)
+
+    def record(self, rid: int, name: str, t0: float, t1: float,
+               **args) -> None:
+        """Keep a front-door span on request ``rid``'s trace track (when
+        the engine traces)."""
+        if self._telemetry is not None and self._telemetry.trace is not None:
+            self._telemetry.trace.request_record(rid, name, t0, t1, **args)
 
     # ---- engine loop -------------------------------------------------------
 
@@ -343,8 +405,6 @@ class ServingServer:
                 # stays under telemetry.sparsity)
                 out["sparsity"] = {
                     "mean_ffn_sparsity": sp["mean_ffn_sparsity"],
-                    "mfu": sp["mfu"],
                     "flops_reduction": sp["flops_reduction"],
-                    "tokens_per_joule_proxy": sp["tokens_per_joule_proxy"],
                 }
         return out

@@ -27,6 +27,11 @@ from repro.models import lm
 from repro.serving import sampling as sampling_mod
 
 
+def call(entry: str, fn, *args):
+    """Call the jitted program ``fn`` of entry point ``entry`` as it is."""
+    return fn(*args)
+
+
 class Drafter:
     """Runs the k-token draft loop under the draft backend's config."""
 
@@ -37,21 +42,19 @@ class Drafter:
         # in/out_shardings (params + pools sharded, host args replicated)
         # so the whole k-step scan compiles under the mesh
         self.jit_shardings: Dict = {}
-        # telemetry: the engine installs a callback fired on every
-        # bucketed-shape cache miss (a fresh XLA compile of the draft scan)
-        self.on_compile = None
+        # dispatch(entry, fn, *args) calls a jitted program; the engine
+        # installs its own, which times a program's first (compiling) call
+        self.dispatch = call
         self._fns: Dict[Tuple[int, bool], callable] = {}
 
     def _jit(self, padded_batch: int, greedy: bool):
         if (padded_batch, greedy) not in self._fns:
-            if self.on_compile is not None:
-                self.on_compile("draft")
             cfg, k = self.cfg, self.k
 
             @functools.partial(jax.jit, donate_argnums=(1,),
                                **self.jit_shardings)
-            def fn(params, pools, bt, sl0, tok0, draft_len, keys, temps,
-                   topks, topps):
+            def draft_step(params, pools, bt, sl0, tok0, draft_len, keys,
+                           temps, topks, topps):
                 # keys: (k, B, 2) per-step per-request draft keys
                 def step(carry, xs):
                     tok, pools = carry
@@ -71,7 +74,7 @@ class Drafter:
                 # scan stacks along the step axis -> (B, k[, V])
                 return (jnp.swapaxes(toks, 0, 1),
                         jnp.swapaxes(logits, 0, 1), pools)
-            self._fns[(padded_batch, greedy)] = fn
+            self._fns[(padded_batch, greedy)] = draft_step
         return self._fns[(padded_batch, greedy)]
 
     def draft(self, params, pools, bt, sl0, tok0, draft_len, keys, temps,
@@ -85,5 +88,5 @@ class Drafter:
         (draft_tokens (B, k), draft_logits (B, k, V), pools).
         """
         fn = self._jit(bt.shape[0], greedy)
-        return fn(params, pools, bt, sl0, tok0, draft_len, keys, temps,
-                  topks, topps)
+        return self.dispatch("draft", fn, params, pools, bt, sl0, tok0,
+                             draft_len, keys, temps, topks, topps)

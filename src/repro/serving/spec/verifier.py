@@ -39,6 +39,7 @@ from repro.config import ModelConfig
 from repro.models import lm
 from repro.serving import sampling as sampling_mod
 from repro.serving.request import Request
+from repro.serving.spec.drafter import call
 
 
 class Verifier:
@@ -50,26 +51,24 @@ class Verifier:
         # tensor-parallel serving: the engine installs explicit
         # in/out_shardings so the batched verify compiles under the mesh
         self.jit_shardings: Dict = {}
-        # telemetry: engine-installed callback fired per bucketed-shape
-        # cache miss (a fresh XLA compile of the batched verify)
-        self.on_compile = None
+        # dispatch(entry, fn, *args) calls a jitted program; the engine
+        # installs its own, which times a program's first (compiling) call
+        self.dispatch = call
         self._fns: Dict[int, callable] = {}
 
     # ------------------------------------------------------------ device side
 
     def _jit(self, padded_batch: int):
         if padded_batch not in self._fns:
-            if self.on_compile is not None:
-                self.on_compile("verify")
             cfg = self.cfg
 
             @functools.partial(jax.jit, donate_argnums=(1,),
                                **self.jit_shardings)
-            def fn(params, pools, bt, start, num_new, toks):
+            def verify_step(params, pools, bt, start, num_new, toks):
                 logits, pools = lm.paged_verify(params, pools, bt, start,
                                                 num_new, toks, cfg)
                 return logits.astype(jnp.float32), pools
-            self._fns[padded_batch] = fn
+            self._fns[padded_batch] = verify_step
         return self._fns[padded_batch]
 
     def verify(self, params, pools, bt, start, num_new, toks):
@@ -79,7 +78,8 @@ class Verifier:
         (logits float32 (B, k+1, V), pools); logits row j scores the token
         following position start + j."""
         fn = self._jit(bt.shape[0])
-        return fn(params, pools, bt, start, num_new, toks)
+        return self.dispatch("verify", fn, params, pools, bt, start, num_new,
+                             toks)
 
     # -------------------------------------------------------------- host side
 

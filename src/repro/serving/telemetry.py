@@ -20,15 +20,24 @@ counters, gauges, and fixed-bucket histograms. The registry is:
 docs/observability.md) against a registry; ``Telemetry`` is the facade the
 engine holds — metrics + the span/trace recorder from ``trace.py`` — with
 the per-lifecycle hooks (``on_submit`` / ``on_admit`` / ``on_tokens`` /
-``on_spec`` / ``phase`` / ``on_step`` / ...) the engine calls so
-instrumentation stays out of the scheduling logic.
+``on_spec`` / ``on_step`` / ...) and the ``span`` context manager the engine
+calls so instrumentation stays out of the scheduling logic.
+
+A ``span`` is one timed engine phase, written three ways: to the
+``TraceRecorder`` (track ``tid 0``, ``args.step``), to
+``serving_step_phase_seconds``, and as a ``jax.profiler.TraceAnnotation``
+named ``engine.<phase>``, which puts it in the profiler's host plane on the
+device's clock. With no profiler running an annotation costs one check.
 """
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from bisect import bisect_left
-from typing import Dict, Iterable, List, Optional, Sequence, Tuple
+from typing import Dict, Iterable, Iterator, List, Optional, Sequence, Tuple
+
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.observability import accounting
 from repro.serving.trace import (SPAN_CANCEL, SPAN_DECODE, SPAN_FINISH,
@@ -61,6 +70,8 @@ PHASE_LAUNCH = "launch"
 PHASE_COLLECT = "collect"
 PHASE_OVERLAP = "overlap"        # previous launch -> its collect: wall time
 #                                  device(N) ran concurrently with host work
+PHASE_COMPILE = "compile"        # ``compile.<entry>``: the first dispatch of
+#                                  a step program, which compiles it
 
 
 def _fmt(v: float) -> str:
@@ -293,7 +304,9 @@ class ServingMetrics:
             "serving_step_phase_seconds",
             "Engine step time split by phase (admission / prefill / decode "
             "/ draft / verify / sample / host_sync / cancel / step; "
-            "pipelined mode adds plan / launch / collect / overlap)",
+            "pipelined mode adds plan / launch / collect / overlap; "
+            "compile.<entry> is a step program's first, compiling, "
+            "dispatch)",
             ("phase",))
         self.steps_total = r.counter(
             "serving_steps_total", "Engine step() iterations")
@@ -367,7 +380,7 @@ class ServingMetrics:
             "Publish-to-claim wait of migrated KV entries (disagg)")
         self.jit_compiles_total = r.counter(
             "serving_jit_compiles_total",
-            "Bucketed-shape JIT cache misses by entrypoint "
+            "Step programs compiled at their first dispatch, by entrypoint "
             "(decode / prefill / draft / verify)",
             ("entry",))
         self.warmup_seconds = r.gauge(
@@ -400,15 +413,6 @@ class ServingMetrics:
         self.dense_flops_total = r.counter(
             "serving_dense_flops_total",
             "Dense-equivalent model FLOPs for the same probed forwards")
-        self.mfu = r.gauge(
-            "serving_mfu",
-            "Live MFU estimate: dense-equivalent FLOPs of the last step "
-            "over wall time x chips x the device's published peak (unset "
-            "on a device without one)")
-        self.tokens_per_joule = r.gauge(
-            "serving_tokens_per_joule_proxy",
-            "Committed tokens per joule at chip TDP (an energy proxy, not "
-            "a measurement)")
 
 
 def _lat_key(label_set: Dict[str, str]) -> str:
@@ -446,8 +450,6 @@ class Telemetry:
         self._last_token_t: Dict[int, float] = {}   # rid -> last commit time
         self._kv_prev = {"cow": 0, "evict": 0}      # counter deltas
         self._compute: Optional[Dict] = None        # armed by attach_compute
-        self._win_flops = 0.0                       # dense-equiv, this step
-        self._win_tokens = 0                        # committed, this step
         self._sparsity_sum = 0.0                    # running mean numerator
         self._sparsity_n = 0
 
@@ -512,7 +514,6 @@ class Telemetry:
             return
         now = time.perf_counter() if now is None else now
         self.metrics.tokens_total.inc(n)
-        self._win_tokens += n
         tier = str(req.priority)
         last = self._last_token_t.get(req.rid)
         if last is None:
@@ -554,14 +555,12 @@ class Telemetry:
 
     # ---- sparsity / compute accounting -------------------------------------
 
-    def attach_compute(self, cfg, n_params: int, chips: int = 1,
-                       peak_flops: Optional[float] = None) -> None:
+    def attach_compute(self, cfg, n_params: int, chips: int = 1) -> None:
         """Arm the sparsity/compute cost model. The engine calls this once
-        at build time when sparsity probing is enabled; ``on_ffn`` and the
-        MFU/energy gauges stay inert until it does. ``peak_flops`` is the
-        device's published per-chip peak; without one no MFU is set."""
+        at build time when sparsity probing is enabled; ``on_ffn`` stays
+        inert until it does."""
         self._compute = {"cfg": cfg, "n_params": int(n_params),
-                         "chips": int(chips), "peak_flops": peak_flops}
+                         "chips": int(chips)}
 
     def on_ffn(self, tokens: int, nnz_per_layer, tile_frac_per_layer=None,
                ffn_present=None, impl: Optional[str] = None) -> None:
@@ -583,20 +582,41 @@ class Telemetry:
             m.tile_occupancy.observe(lc.tile_frac)
         m.effective_flops_total.inc(max(report.model_effective_flops, 0.0))
         m.dense_flops_total.inc(max(report.model_dense_flops, 0.0))
-        self._win_flops += report.model_dense_flops
         self._sparsity_sum += report.mean_sparsity
         self._sparsity_n += 1
 
     # ---- engine step -------------------------------------------------------
 
-    def phase(self, name: str, t0: float, t1: float, step: int) -> None:
-        """One timed engine phase within one step."""
+    @contextlib.contextmanager
+    def span(self, name: str, step: int) -> Iterator[None]:
+        """Time one engine phase of step ``step``: the recorder's phase
+        span, the phase histogram, and the ``engine.<name>`` profiler
+        annotation."""
+        t0 = time.perf_counter()
+        with TraceAnnotation(f"engine.{name}"):
+            yield
+        t1 = time.perf_counter()
         self.metrics.step_phase_seconds.observe(t1 - t0, phase=name)
         if self.trace is not None:
             self.trace.phase_span(name, t0, t1, step)
 
-    def on_compile(self, entry: str) -> None:
+    def step_span(self, step: int) -> StepTraceAnnotation:
+        """The profiler's step marker around one ``engine.step()``."""
+        return StepTraceAnnotation("engine.step", step_num=step)
+
+    def overlap(self, t_launched: float, t_collect: float, step: int) -> None:
+        """The previous launch -> its collect. It spans two steps, so it is
+        recorded but not annotated."""
+        self.metrics.step_phase_seconds.observe(t_collect - t_launched,
+                                                phase=PHASE_OVERLAP)
+        if self.trace is not None:
+            self.trace.phase_span(PHASE_OVERLAP, t_launched, t_collect, step)
+
+    def compiling(self, entry: str, step: int):
+        """The first dispatch of a new step program: counted, and timed as
+        the ``compile.<entry>`` span."""
         self.metrics.jit_compiles_total.inc(entry=entry)
+        return self.span(f"{PHASE_COMPILE}.{entry}", step)
 
     def on_warmup(self, seconds: float, shapes: int) -> None:
         """Record a completed startup precompile pass (engine.warmup)."""
@@ -623,15 +643,6 @@ class Telemetry:
             if delta > 0:
                 m.kv_events_total.inc(delta, event=event)
             self._kv_prev[event] = occ[key]
-        if self._compute is not None:
-            chips = self._compute["chips"]
-            peak = self._compute["peak_flops"]
-            if peak is not None:
-                m.mfu.set(accounting.mfu(self._win_flops, wall_s, chips,
-                                         peak))
-            m.tokens_per_joule.set(accounting.tokens_per_joule(
-                self._win_tokens, wall_s, chips))
-            self._win_flops, self._win_tokens = 0.0, 0
 
     # ---- summaries ---------------------------------------------------------
 
@@ -669,9 +680,6 @@ class Telemetry:
                 "effective_flops_total": eff,
                 "dense_flops_total": dense,
                 "flops_reduction": 1.0 - eff / dense if dense else None,
-                "mfu": m.mfu.value()
-                if self._compute["peak_flops"] is not None else None,
-                "tokens_per_joule_proxy": m.tokens_per_joule.value(),
             }
         return {
             "sparsity": sparsity,

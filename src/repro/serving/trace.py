@@ -18,8 +18,13 @@ The ``TraceRecorder`` additionally keeps an engine-level timeline — one
 span per timed step phase (decode / draft / verify / admission / prefill /
 ...; the pipelined engine adds plan / launch / collect plus an ``overlap``
 span covering launch(N)→collect(N), i.e. the window where device compute
-and host planning ran concurrently) — and renders everything as
-Chrome-trace JSON (the ``traceEvents``
+and host planning ran concurrently; ``compile.<entry>`` marks the first
+dispatch of a step program, which compiles it) — and the HTTP front door's
+records of each request (``http.receive``: handler entry until ``submit``
+returned; ``http.write``: one per SSE chunk, with ``tokens`` and ``lag_ms``
+from the commit of its earliest token to the flush). Those are kept apart
+from the request's own spans, so the writes after its FINISH are kept too.
+Everything renders as Chrome-trace JSON (the ``traceEvents``
 array format): load the file in ``chrome://tracing`` or https://ui.perfetto.dev
 to see the whole-engine step timeline with one track per request. Event
 storage is bounded (``max_events``), oldest dropped first, so a long-lived
@@ -91,11 +96,13 @@ class TraceRecorder:
             deque(maxlen=max_events)
         self._retired: Deque[Tuple[int, Tuple[SpanEvent, ...]]] = \
             deque(maxlen=max_events)
+        self._front: Deque[Tuple[int, SpanEvent]] = deque(maxlen=max_events)
         self.t0 = time.perf_counter()          # export timebase
 
     def __len__(self) -> int:
         with self._lock:
-            return len(self._phases) + sum(len(s) for _, s in self._retired)
+            return len(self._phases) + len(self._front) + \
+                sum(len(s) for _, s in self._retired)
 
     # ---- request track (span state lives on the request) -------------------
 
@@ -130,6 +137,14 @@ class TraceRecorder:
         with self._lock:
             self._retired.append((req.rid, tuple(req.spans)))
 
+    def request_record(self, rid: int, name: str, t0: float, t1: float,
+                       **args) -> None:
+        """A front-door span of request ``rid`` (``http.*``), kept apart from
+        the request's lifecycle spans so it outlives the request."""
+        ev = SpanEvent(name, t0, t1, tuple(sorted(args.items())))
+        with self._lock:
+            self._front.append((rid, ev))
+
     # ---- engine track ------------------------------------------------------
 
     def phase_span(self, name: str, t0: float, t1: float, step: int) -> None:
@@ -150,12 +165,15 @@ class TraceRecorder:
         with self._lock:
             phases = list(self._phases)
             retired = list(self._retired)
+            front = list(self._front)
         for name, t0, t1, step in phases:
             ev.append({"ph": "X", "pid": 0, "tid": 0, "name": name,
                        "ts": us(t0), "dur": (t1 - t0) * 1e6,
                        "args": {"step": step}})
         now = time.perf_counter()
-        tracks = list(retired)
+        tracks: Dict[int, List[SpanEvent]] = {}
+        for rid, spans in retired:
+            tracks.setdefault(rid, []).extend(spans)
         for req in live_requests:
             spans = list(getattr(req, "spans", None) or ())
             open_ = getattr(req, "span_open", None)
@@ -163,8 +181,10 @@ class TraceRecorder:
                 name, t0, args = open_
                 spans.append(SpanEvent(name, t0, now, args))
             if spans:
-                tracks.append((req.rid, tuple(spans)))
-        for rid, spans in tracks:
+                tracks.setdefault(req.rid, []).extend(spans)
+        for rid, span in front:
+            tracks.setdefault(rid, []).append(span)
+        for rid, spans in tracks.items():
             tid = rid + 1
             ev.append({"ph": "M", "pid": 0, "tid": tid,
                        "name": "thread_name",

@@ -207,20 +207,11 @@ def test_stats_sparsity_rollup(server):
     assert f'serving_ffn_sparsity{{layer="{cfg.num_layers - 1}"}}' in text
     assert "# TYPE serving_effective_flops_total counter" in text
     assert "# TYPE serving_tile_occupancy_ratio histogram" in text
-    assert "serving_mfu" in text
-    assert "serving_tokens_per_joule_proxy" in text
     stats = json.load(urllib.request.urlopen(_url(srv, "/v1/stats"),
                                              timeout=10))
     sp = stats["sparsity"]                       # compact rollup
     assert 0.0 <= sp["mean_ffn_sparsity"] <= 1.0
     assert sp["flops_reduction"] is not None
-    # MFU only on a device with a published peak (never this CPU's)
-    from repro.observability.accounting import PEAKS
-    if jax.devices()[0].device_kind not in PEAKS:
-        assert sp["mfu"] is None
-    else:
-        assert sp["mfu"] >= 0.0
-    assert sp["tokens_per_joule_proxy"] >= 0.0
     full = stats["telemetry"]["sparsity"]        # full block
     assert len(full["per_layer_sparsity"]) == cfg.num_layers
     assert full["dense_flops_total"] >= full["effective_flops_total"] > 0

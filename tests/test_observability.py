@@ -106,13 +106,10 @@ class TestCostModel:
             expect -= cfg.padded_vocab * cfg.d_model
         assert accounting.matmul_params(cfg, n) == expect
 
-    def test_mfu_and_tokens_per_joule(self):
+    def test_mfu(self):
         assert accounting.mfu(1e12, 1.0, chips=1, peak=1e13) \
             == pytest.approx(0.1)
         assert accounting.mfu(1e12, 0.0, chips=1, peak=1e13) == 0.0
-        assert accounting.tokens_per_joule(170.0, 1.0, chips=1,
-                                           tdp_w=170.0) == pytest.approx(1.0)
-        assert accounting.tokens_per_joule(1, 0.0) == 0.0
 
 
 class TestSparsityReport:
@@ -288,14 +285,10 @@ class TestServingProbe:
         for i in range(cfg.num_layers):
             assert 0.0 <= m.ffn_sparsity.value(layer=str(i)) <= 1.0
         assert m.tile_occupancy.snapshot()["count"] > 0
-        # live MFU/energy gauges were set by on_step
-        assert m.mfu.value() >= 0
-        assert m.tokens_per_joule.value() > 0
         # exposition + summary rollups
         text = tm.registry.render_prometheus()
         assert 'serving_ffn_sparsity{layer="0"}' in text
         assert "serving_effective_flops_total" in text
-        assert "serving_mfu" in text
         sp = tm.summary()["sparsity"]
         assert 0.0 <= sp["mean_ffn_sparsity"] <= 1.0
         assert sp["flops_reduction"] is not None
