@@ -195,18 +195,27 @@ def check_attention_kernels(cfg, key, checks: Checks) -> None:
     span = width * BLOCK_SIZE
     nb = 1 + b * width
     ks = jax.random.split(key, 8)
-    kpool = jax.random.normal(ks[0], (nb, BLOCK_SIZE, hkv, hd)).astype(dtype)
-    vpool = jax.random.normal(ks[1], (nb, BLOCK_SIZE, hkv, hd)).astype(dtype)
+    # two layers of (L, N, bs, Hkv*hd) pools; the kernels read the second,
+    # as a layer scan hands them the whole pools and the layer index, and
+    # the oracles read that layer's (N, bs, Hkv, hd) pages
+    layers, layer = 2, jnp.int32(1)
+    kpool = jax.random.normal(
+        ks[0], (layers, nb, BLOCK_SIZE, hkv * hd)).astype(dtype)
+    vpool = jax.random.normal(
+        ks[1], (layers, nb, BLOCK_SIZE, hkv * hd)).astype(dtype)
+    kpages = kpool[layer].reshape(nb, BLOCK_SIZE, hkv, hd)
+    vpages = vpool[layer].reshape(nb, BLOCK_SIZE, hkv, hd)
     tables = (1 + jax.random.permutation(ks[2], nb - 1)).reshape(b, width)
     tables = tables.astype(jnp.int32)
-    shape = f"pool ({nb}, {BLOCK_SIZE}, {hkv}, {hd}), table width {width}"
+    shape = (f"pool ({layers}, {nb}, {BLOCK_SIZE}, {hkv * hd}), "
+             f"table width {width}")
 
     q = jax.random.normal(ks[3], (b, 1, h, hd)).astype(dtype)
     lens = jax.random.randint(ks[4], (b,), 0, span, jnp.int32)
     got = paged_decode_attention_pallas(q, kpool, vpool, tables, lens,
-                                        interpret=False)
-    err = rel_err(got, ref.paged_attention_decode(q, kpool, vpool, tables,
-                                                  lens))
+                                        layer, interpret=False)
+    err = rel_err(got, ref.paged_attention_decode(q, kpages, vpages,
+                                                  tables, lens))
     checks.check("kernels", f"paged_decode_attention B={b} H={h} {shape}",
                  err <= KERNEL_TOL, f"error {err:.3e} (tolerance "
                                     f"{KERNEL_TOL})")
@@ -216,9 +225,9 @@ def check_attention_kernels(cfg, key, checks: Checks) -> None:
     lens = jax.random.randint(ks[6], (rows,), 0, span - chunk, jnp.int32)
     num_new = jax.random.randint(ks[7], (rows,), 1, chunk + 1, jnp.int32)
     got = paged_chunk_attention_pallas(q, kpool, vpool, tables[:rows], lens,
-                                       num_new, interpret=False)
-    want = ref.paged_attention_extend(q, kpool, vpool, tables[:rows], lens,
-                                      num_new)
+                                       num_new, layer, interpret=False)
+    want = ref.paged_attention_extend(q, kpages, vpages, tables[:rows],
+                                      lens, num_new)
     # rows past a request's num_new are padding, garbage in both
     live = np.arange(chunk)[None, :] < np.asarray(num_new)[:, None]
     err = rel_err(np.asarray(got, np.float32)[live],

@@ -138,17 +138,17 @@ def cache_spec(path: str, shape: Tuple[int, ...], cfg, mesh: Mesh) -> P:
     """KV / SSM caches. Layout conventions (leading layer-stack dim):
     k,v: (L, B, S, Hkv, hd); state: (L, B, H, hd, N); conv: (L, B, W, C);
     wkv: (L, B, H, hd, hd); shift: (L, B, D); xk/xv: (L, B, P, Hkv, hd);
-    paged serving pools kpool/vpool: (L, NB, BS, Hkv, hd)."""
+    paged serving pools kpool/vpool: (L, NB, BS, Hkv*hd)."""
     tp = tp_size(mesh)
     dsz = data_size(mesh)
     dp = dp_spec(mesh)
     spec: list = [None] * len(shape)
-    if re.search(r"(^|/)[kv]pool$", path) and len(shape) == 5:
-        # paged pool: ONLY the kv-head axis may split. Dim 1 is the physical
-        # block id — allocation is a host-side free list and any block can
-        # belong to any request, so the block axis must stay whole on every
-        # device (a block-sharded pool would turn each table gather into a
-        # cross-device shuffle). Seq-dim fallback is likewise unavailable:
+    if re.search(r"(^|/)[kv]pool$", path) and len(shape) == 4:
+        # paged pool: ONLY the Hkv*hd row may split, into whole kv heads.
+        # Dim 1 is the physical block id — allocation is a host-side free
+        # list and any block can belong to any request, so the block axis
+        # must stay whole on every device (a block-sharded pool would turn
+        # each table gather into a cross-device shuffle). Seq-dim fallback is likewise unavailable:
         # dim 2 is the *intra-block* offset, not a sequence.
         if cfg.num_kv_heads % tp == 0:
             spec[3] = "model"
@@ -258,9 +258,10 @@ def make_paged_pool_shardings(cfg, mesh: Mesh, num_blocks: int,
                               block_size: int):
     """NamedShardings for the serving engine's paged KV pools, via the same
     ``cache_spec`` rules the training/decode caches use (kpool/vpool split
-    the kv-head axis over ``model``; the block axis stays whole)."""
-    shape = (cfg.num_layers, num_blocks, block_size, cfg.num_kv_heads,
-             cfg.resolved_head_dim)
+    the Hkv*hd row into whole kv heads over ``model``; the block axis stays
+    whole)."""
+    shape = (cfg.num_layers, num_blocks, block_size,
+             cfg.num_kv_heads * cfg.resolved_head_dim)
     return {name: NamedSharding(mesh, cache_spec(name, shape, cfg, mesh))
             for name in ("kpool", "vpool")}
 

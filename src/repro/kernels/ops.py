@@ -130,28 +130,40 @@ def tile_skip_ffn(x, wg, wu, wd, tile: int, act: str = "relu",
     return y.astype(x.dtype), h
 
 
-def paged_attention_decode(q, kpool, vpool, block_tables, seq_lens,
+def _layer_pool(pool, layer, hd: int):
+    """Layer ``layer`` of (L, N, bs, Hkv*hd) pools as the oracles' (N, bs,
+    Hkv, hd) page pool."""
+    return pool[layer].reshape(*pool.shape[1:3], -1, hd)
+
+
+def paged_attention_decode(q, kpool, vpool, block_tables, seq_lens, layer,
                            mode: str):
-    """Paged decode attention read (scatter happens in the caller); ``mode``
-    is the attention backend's kernel mode (ref | pallas | interpret)."""
+    """Paged decode attention read (scatter happens in the caller) of layer
+    ``layer`` of the (L, N, bs, Hkv*hd) pools; ``mode`` is the attention
+    backend's kernel mode (ref | pallas | interpret)."""
     if mode == "ref":
-        return ref.paged_attention_decode(q, kpool, vpool, block_tables,
-                                          seq_lens)
+        hd = q.shape[-1]
+        return ref.paged_attention_decode(
+            q, _layer_pool(kpool, layer, hd), _layer_pool(vpool, layer, hd),
+            block_tables, seq_lens)
     from repro.kernels.paged_decode_attention import (
         paged_decode_attention_pallas)
     return paged_decode_attention_pallas(
-        q, kpool, vpool, block_tables, seq_lens,
+        q, kpool, vpool, block_tables, seq_lens, layer,
         interpret=(mode == "interpret"))
 
 
 def paged_attention_extend(q, kpool, vpool, block_tables, seq_lens, num_new,
-                           mode: str):
-    """Chunk-append attention read (prefill / chunked prefill / verify)."""
+                           layer, mode: str):
+    """Chunk-append attention read (prefill / chunked prefill / verify) of
+    layer ``layer`` of the (L, N, bs, Hkv*hd) pools."""
     if mode == "ref":
-        return ref.paged_attention_extend(q, kpool, vpool, block_tables,
-                                          seq_lens, num_new)
+        hd = q.shape[-1]
+        return ref.paged_attention_extend(
+            q, _layer_pool(kpool, layer, hd), _layer_pool(vpool, layer, hd),
+            block_tables, seq_lens, num_new)
     from repro.kernels.paged_chunk_attention import (
         paged_chunk_attention_pallas)
     return paged_chunk_attention_pallas(
-        q, kpool, vpool, block_tables, seq_lens, num_new,
+        q, kpool, vpool, block_tables, seq_lens, num_new, layer,
         interpret=(mode == "interpret"))

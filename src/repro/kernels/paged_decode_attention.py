@@ -1,18 +1,23 @@
 """Fused paged decode-attention Pallas kernel (flash-decoding style).
 
 One query token per request attends its paged KV history *in place*: the
-block table is a scalar-prefetch operand, so each grid step DMAs exactly one
-live page out of the pool — no (B, W*block_size, H, hd) gather and no
-``repeat_kv`` materialization (GQA is a head-group axis on the query side).
+kernel takes the whole (L, N, bs, Hkv*hd) pools that the layer scan
+carries, with the layer index and the block table as scalar-prefetch
+operands, so each grid step DMAs exactly one live page of one layer out of
+the pool — no per-layer slice of the pools, no (B, W*block_size, H, hd)
+gather and no ``repeat_kv`` materialization (GQA is a head-group axis on
+the query side).
 
 Grid: ``(B, num_splits, pages_per_split)`` — pages innermost so the
 online-softmax scratch carries across a split's pages; splits are merged in
 plain jnp afterwards (second-stage reduce). Each step fetches one whole page
-``(bs, Hkv, hd)`` — every kv head at once, so the block's last two dims are
-the pool's own — and loops over the kv heads inside the kernel. Pages at or
-past a request's live span (``page * block_size > seq_len``) are skipped via
-``pl.when``: compute per step is proportional to the request's actual
-``seq_len``, not the padded table width.
+``(1, 1, bs, Hkv*hd)`` at ``(layer, table[b, page])`` — every kv head at
+once, so the block's last two dims are the pool's own — and loops over the
+kv heads inside the kernel, head ``h`` being lanes ``[h*hd, (h+1)*hd)`` of
+each token's row. Pages at or past a request's live span
+(``page * block_size > seq_len``) are skipped via ``pl.when``: compute per
+step is proportional to the request's actual ``seq_len``, not the padded
+table width.
 
 Numerics mirror ``kernels.ref.paged_attention_decode``: f32 logits/softmax,
 -1e30 mask, 1/sqrt(hd) scale. The null block (id 0) backs padded batch rows
@@ -30,9 +35,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _decode_kernel(bt_ref, sl_ref, q_ref, k_ref, v_ref,
+def _decode_kernel(layer_ref, bt_ref, sl_ref, q_ref, k_ref, v_ref,
                    o_ref, m_ref, l_ref,
-                   m_s, l_s, acc_s, *, bs, width, pages, scale, hkv):
+                   m_s, l_s, acc_s, *, bs, width, pages, scale, hkv, hd):
     b = pl.program_id(0)
     pi = pl.program_id(2)
     page = pl.program_id(1) * pages + pi
@@ -50,8 +55,9 @@ def _decode_kernel(bt_ref, sl_ref, q_ref, k_ref, v_ref,
     def _compute():
         for h in range(hkv):                # one fetched page, every kv head
             q = q_ref[0, h].astype(jnp.float32)                # (G, hd)
-            k = k_ref[0, :, h, :].astype(jnp.float32)          # (bs, hd)
-            v = v_ref[0, :, h, :].astype(jnp.float32)
+            lanes = slice(h * hd, (h + 1) * hd)
+            k = k_ref[0, 0, :, lanes].astype(jnp.float32)      # (bs, hd)
+            v = v_ref[0, 0, :, lanes].astype(jnp.float32)
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale    # (G, bs)
@@ -79,12 +85,14 @@ def _pick_splits(width: int, num_splits: int) -> int:
 
 @functools.partial(jax.jit, static_argnames=("num_splits", "interpret"))
 def paged_decode_attention_pallas(q, kpool, vpool, block_tables, seq_lens,
-                                  *, num_splits: int = 4,
+                                  layer, *, num_splits: int = 4,
                                   interpret: bool = False):
-    """q: (B, 1, H, hd); pools: (N, bs, Hkv, hd); block_tables: (B, W);
-    seq_lens: (B,). Returns (B, 1, H, hd) in q.dtype."""
+    """q: (B, 1, H, hd); pools: (L, N, bs, Hkv*hd); block_tables: (B, W);
+    seq_lens: (B,); layer: int32 scalar, the pools' layer to read. Returns
+    (B, 1, H, hd) in q.dtype."""
     b, _, h, hd = q.shape
-    _, bs, hkv, _ = kpool.shape
+    _, _, bs, row = kpool.shape
+    hkv = row // hd
     width = block_tables.shape[1]
     g = h // hkv
     ns = _pick_splits(width, num_splits)
@@ -93,30 +101,30 @@ def paged_decode_attention_pallas(q, kpool, vpool, block_tables, seq_lens,
     # head h = hkv_idx * G + g: reshape matches repeat_kv's group broadcast
     qg = q.reshape(b, hkv, g, hd)
     kernel = functools.partial(_decode_kernel, bs=bs, width=width,
-                               pages=pages, scale=scale, hkv=hkv)
+                               pages=pages, scale=scale, hkv=hkv, hd=hd)
 
-    def kv_map(bi, si, pi, bt_ref, sl_ref):
+    def kv_map(bi, si, pi, layer_ref, bt_ref, sl_ref):
         page = jnp.minimum(si * pages + pi, width - 1)
-        return (bt_ref[bi, page], 0, 0, 0)
+        return (layer_ref[0], bt_ref[bi, page], 0, 0)
 
     o, m, l = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(b, ns, pages),
             in_specs=[
                 pl.BlockSpec((1, hkv, g, hd),
-                             lambda bi, si, pi, bt, sl: (bi, 0, 0, 0)),
-                pl.BlockSpec((1, bs, hkv, hd), kv_map),
-                pl.BlockSpec((1, bs, hkv, hd), kv_map),
+                             lambda bi, si, pi, *_: (bi, 0, 0, 0)),
+                pl.BlockSpec((1, 1, bs, row), kv_map),
+                pl.BlockSpec((1, 1, bs, row), kv_map),
             ],
             out_specs=[
                 pl.BlockSpec((1, 1, hkv, g, hd),
-                             lambda bi, si, pi, bt, sl: (bi, si, 0, 0, 0)),
+                             lambda bi, si, pi, *_: (bi, si, 0, 0, 0)),
                 pl.BlockSpec((1, 1, hkv, g, 1),
-                             lambda bi, si, pi, bt, sl: (bi, si, 0, 0, 0)),
+                             lambda bi, si, pi, *_: (bi, si, 0, 0, 0)),
                 pl.BlockSpec((1, 1, hkv, g, 1),
-                             lambda bi, si, pi, bt, sl: (bi, si, 0, 0, 0)),
+                             lambda bi, si, pi, *_: (bi, si, 0, 0, 0)),
             ],
             scratch_shapes=[pltpu.VMEM((hkv, g, 1), jnp.float32),
                             pltpu.VMEM((hkv, g, 1), jnp.float32),
@@ -127,7 +135,8 @@ def paged_decode_attention_pallas(q, kpool, vpool, block_tables, seq_lens,
                    jax.ShapeDtypeStruct((b, ns, hkv, g, 1), jnp.float32)],
         interpret=interpret,
         name="paged_decode_attention",
-    )(block_tables, seq_lens, qg, kpool, vpool)
+    )(jnp.asarray(layer, jnp.int32).reshape(1), block_tables, seq_lens,
+      qg, kpool, vpool)
 
     # second-stage reduce: merge per-split partial softmaxes. Dead splits
     # (every page skipped) carry m = -1e30, l = 0 and contribute exactly 0.

@@ -198,8 +198,13 @@ def _banded(q, k, v, scale, band_chunk: int, lookback: int,
 def _paged_attention(q, k, v, cache, cfg, n_heads, scale):
     """Paged-KV attention (serving engine).
 
-    cache = {"kpool", "vpool", "block_tables", "seq_lens"} for ONE layer:
-      kpool/vpool:   (num_blocks, block_size, Hkv, hd) page pool
+    cache = {"kpool", "vpool", "layer", "block_tables", "seq_lens"}:
+      kpool/vpool:   (L, num_blocks, block_size, Hkv*hd) page pools of
+                     EVERY layer, one row of every kv head's K (V) per
+                     token — the layer scan carries them whole and this
+                     call updates them in place
+      layer:         int32 scalar, the layer this call reads and writes
+                     (K/V land at ``[layer, blk, off]``)
       block_tables:  (B, W) int32 physical block ids (0 = reserved null block)
       seq_lens:      (B,) int32 tokens already cached per request
 
@@ -224,13 +229,15 @@ def _paged_attention(q, k, v, cache, cfg, n_heads, scale):
     shared plain JAX; ``cfg.attn_backend`` selects how the scattered pools
     are *read*. "ref" is the inline gather-pages SDPA below (the numerics
     reference); "pallas"/"interpret" dispatch to the fused paged-attention
-    kernels through ``repro.serving.attention`` (block tables consumed
-    in-kernel — only live pages are touched, no repeat_kv materialization).
+    kernels through ``repro.serving.attention`` (layer index and block
+    tables consumed in-kernel — only live pages of the layer are touched,
+    no repeat_kv materialization). Neither reads a per-layer slice of the
+    pools, so nothing pool-sized is copied.
     """
-    kpool, vpool = cache["kpool"], cache["vpool"]
+    kpool, vpool, layer = cache["kpool"], cache["vpool"], cache["layer"]
     bt, sl = cache["block_tables"], cache["seq_lens"]
     b, s, hkv, hd = k.shape
-    bs_blk = kpool.shape[1]
+    bs_blk = kpool.shape[2]
     backend = getattr(cfg, "attn_backend", "ref")
     # tensor-parallel serving: per-head tensors split over the model axis,
     # matching the pool's kv-head sharding, so scatter/gather and the SDPA
@@ -246,15 +253,15 @@ def _paged_attention(q, k, v, cache, cfg, n_heads, scale):
             wv = cache["write_valid"]
             blk = jnp.where(wv, blk, 0)
             off = jnp.where(wv, off, 0)
-        kpool = kpool.at[blk, off].set(k[:, 0])
-        vpool = vpool.at[blk, off].set(v[:, 0])
+        kpool = kpool.at[layer, blk, off].set(k[:, 0].reshape(b, -1))
+        vpool = vpool.at[layer, blk, off].set(v[:, 0].reshape(b, -1))
         if backend != "ref":
             out = _attn_backend(backend).forward_decode(
-                q, kpool, vpool, bt, sl)
+                q, kpool, vpool, bt, sl, layer)
         else:
-            kf = shard_act(repeat_kv(kpool[bt].reshape(b, -1, hkv, hd),
+            kf = shard_act(repeat_kv(kpool[layer, bt].reshape(b, -1, hkv, hd),
                                      n_heads), None, None, "model", None)
-            vf = shard_act(repeat_kv(vpool[bt].reshape(b, -1, hkv, hd),
+            vf = shard_act(repeat_kv(vpool[layer, bt].reshape(b, -1, hkv, hd),
                                      n_heads), None, None, "model", None)
             kpos = jnp.arange(kf.shape[1])
             mask = (kpos[None, :] <= sl[:, None])[:, None, None, :]
@@ -267,17 +274,17 @@ def _paged_attention(q, k, v, cache, cfg, n_heads, scale):
         slot = jnp.clip(pos // bs_blk, 0, bt.shape[1] - 1)
         blk = jnp.where(valid, jnp.take_along_axis(bt, slot, axis=1), 0)
         off = jnp.where(valid, pos % bs_blk, 0)
-        kpool = kpool.at[blk.reshape(-1), off.reshape(-1)].set(
-            k.reshape(b * s, hkv, hd))
-        vpool = vpool.at[blk.reshape(-1), off.reshape(-1)].set(
-            v.reshape(b * s, hkv, hd))
+        kpool = kpool.at[layer, blk.reshape(-1), off.reshape(-1)].set(
+            k.reshape(b * s, -1))
+        vpool = vpool.at[layer, blk.reshape(-1), off.reshape(-1)].set(
+            v.reshape(b * s, -1))
         if backend != "ref":
             out = _attn_backend(backend).forward_extend(
-                q, kpool, vpool, bt, sl, cache["num_new"])
+                q, kpool, vpool, bt, sl, cache["num_new"], layer)
         else:
-            kf = shard_act(repeat_kv(kpool[bt].reshape(b, -1, hkv, hd),
+            kf = shard_act(repeat_kv(kpool[layer, bt].reshape(b, -1, hkv, hd),
                                      n_heads), None, None, "model", None)
-            vf = shard_act(repeat_kv(vpool[bt].reshape(b, -1, hkv, hd),
+            vf = shard_act(repeat_kv(vpool[layer, bt].reshape(b, -1, hkv, hd),
                                      n_heads), None, None, "model", None)
             kpos = jnp.arange(kf.shape[1])
             mask = (kpos[None, None, :] <= pos[:, :, None])[:, None]

@@ -457,8 +457,14 @@ def init_paged_cache(cfg: ModelConfig, num_blocks: int,
                      block_size: int) -> Dict[str, jax.Array]:
     """Block-paged KV pool for the serving engine: one shared pool of
     fixed-size blocks instead of a monolithic (L, B, S, ...) cache per call.
-    Layout: (L, num_blocks, block_size, Hkv, hd); block 0 is the null block
-    (scatter target for padding — see repro.serving.kv_cache)."""
+    Layout: (L, num_blocks, block_size, Hkv*hd) — each token's K (V) of
+    every kv head is one head-major row; block 0 is the null block (scatter
+    target for padding — see repro.serving.kv_cache).
+
+    The row is what keeps the pools row-major on a TPU: with a minor dim of
+    hd < 128 the device would lay a (..., Hkv, hd) pool out with the block
+    axis minor-most, and every kernel read of a page would need a
+    transposing copy of the pools first."""
     if cfg.family not in ("dense", "moe"):
         raise NotImplementedError(
             f"paged KV serving supports dense/moe families, got {cfg.family}")
@@ -467,7 +473,7 @@ def init_paged_cache(cfg: ModelConfig, num_blocks: int,
             "paged KV serving does not support windowed/chunked attention yet")
     dtype = _dtype(cfg)
     hkv, hd, L = cfg.num_kv_heads, cfg.resolved_head_dim, cfg.num_layers
-    shape = (L, num_blocks, block_size, hkv, hd)
+    shape = (L, num_blocks, block_size, hkv * hd)
     return {"kpool": jnp.zeros(shape, dtype), "vpool": jnp.zeros(shape, dtype)}
 
 
@@ -475,29 +481,31 @@ def _paged_scan(params, x, pools, cfg, positions, block_tables, seq_lens,
                 num_new=None, write_valid=None, last_rows=None,
                 collect_aux=False):
     fam = cfg.family
+    num_layers = pools["kpool"].shape[0]
 
-    def body(xc, pk):
-        p, kp, vp = pk
-        lc = {"kpool": kp, "vpool": vp, "block_tables": block_tables,
-              "seq_lens": seq_lens}
+    # The whole (L, N, bs, Hkv*hd) pools ride in the carry and each layer
+    # writes its K/V at [l, blk, off] in place. As scan xs / stacked ys
+    # they would be sliced per layer and written into a fresh pool-sized
+    # output, which donation cannot alias: a copy of the pools per program.
+    def body(carry, xs):
+        xc, kp, vp = carry
+        p, layer = xs
+        lc = {"kpool": kp, "vpool": vp, "layer": layer,
+              "block_tables": block_tables, "seq_lens": seq_lens}
         if num_new is not None:
             lc["num_new"] = num_new
         if write_valid is not None:
             lc["write_valid"] = write_valid
         xc, aux, nc = _block_apply(p, xc, cfg, positions, kind="causal",
                                    use_moe=fam == "moe", cache=lc)
-        ys = (nc["kpool"], nc["vpool"])
-        if collect_aux:
-            # two scalars per layer; cheap enough to ship every probed step
-            ys += ({"nnz_mean": aux["nnz_mean"],
-                    "tile_frac": aux["tile_frac"],
-                    "ffn_present": aux["ffn_present"]},)
-        return xc, ys
+        # two scalars per layer; cheap enough to ship every probed step
+        ys = {"nnz_mean": aux["nnz_mean"], "tile_frac": aux["tile_frac"],
+              "ffn_present": aux["ffn_present"]} if collect_aux else None
+        return (xc, nc["kpool"], nc["vpool"]), ys
 
-    x, ys = jax.lax.scan(
-        body, x, (params["blocks"], pools["kpool"], pools["vpool"]))
-    kps, vps = ys[0], ys[1]
-    aux_stack = ys[2] if collect_aux else None
+    (x, kps, vps), aux_stack = jax.lax.scan(
+        body, (x, pools["kpool"], pools["vpool"]),
+        (params["blocks"], jnp.arange(num_layers, dtype=jnp.int32)))
     if last_rows is not None:
         # keep only each row's last valid hidden state before the O(V) head:
         # the engine samples one token per request, so materializing
@@ -519,9 +527,11 @@ def _paged_scan(params, x, pools, cfg, positions, block_tables, seq_lens,
 # jits them with the ``pools`` argument donated, so the input pool buffers
 # are CONSUMED by the call — after dispatch the only valid handle is the
 # returned ``pools``, which callers must rebind (``PagedKVCache.swap_pools``)
-# before the next dispatch. Both logits and pools come back as unresolved
-# device values; nothing here blocks, which is what lets the pipelined
-# engine run host planning while the device step executes.
+# before the next dispatch. The layer scan carries the pools and updates
+# them in place, so the returned pools alias the donated buffers and no
+# step program holds a copy of them. Both logits and pools come back as
+# unresolved device values; nothing here blocks, which is what lets the
+# pipelined engine run host planning while the device step executes.
 
 def paged_prefill(params: Dict, pools: Dict, block_tables: jax.Array,
                   tokens: jax.Array, num_new: jax.Array,

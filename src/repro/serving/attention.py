@@ -7,7 +7,9 @@ token against the paged history) and ``forward_extend`` (a chunk appended
 to history: prefill, chunked/prefix-cached prefill, speculative verify).
 The page scatter, ``write_valid`` null-block routing, and ``num_new``
 padded-tail routing stay shared plain-JAX in ``models.layers`` — backends
-differ only in how the scattered pools are read:
+differ only in how the scattered pools are read. Every backend takes the
+whole (L, N, bs, Hkv*hd) pools the layer scan carries plus the layer index
+to read, so no per-layer slice of the pools is ever materialised:
 
   ref        gather every table page + repeat_kv + masked SDPA (plain JAX —
              the numerics reference every other backend is tested against)
@@ -21,7 +23,7 @@ With no backend named, the platform decides: ``pallas`` on a TPU, ``ref``
 elsewhere (``default_attn_backend``).
 
 Under a tensor-parallel mesh the kernel backends run inside ``shard_map``
-over the ``model`` axis (q/pools head-sharded, tables/lens replicated),
+over the ``model`` axis (q/pools head-sharded, tables/lens/layer replicated),
 matching the ref path's head sharding, so the per-device kernel sees local
 head counts and only the downstream wo projection all-reduces.
 """
@@ -53,12 +55,13 @@ class AttentionBackend(ABC):
         hide the device (called once at engine startup)."""
 
     @abstractmethod
-    def forward_decode(self, q, kpool, vpool, block_tables, seq_lens):
-        """(B, 1, H, hd) decode-attention read over the paged history."""
+    def forward_decode(self, q, kpool, vpool, block_tables, seq_lens, layer):
+        """(B, 1, H, hd) decode-attention read over layer ``layer``'s paged
+        history."""
 
     @abstractmethod
     def forward_extend(self, q, kpool, vpool, block_tables, seq_lens,
-                       num_new):
+                       num_new, layer):
         """(B, S, H, hd) chunk-append read: history + causal-within-chunk."""
 
     def describe(self) -> str:
@@ -74,23 +77,26 @@ class RefAttentionBackend(AttentionBackend):
     name = "ref"
     kernel_mode = "ref"
 
-    def forward_decode(self, q, kpool, vpool, block_tables, seq_lens):
+    def forward_decode(self, q, kpool, vpool, block_tables, seq_lens, layer):
         return ops.paged_attention_decode(q, kpool, vpool, block_tables,
-                                          seq_lens, mode="ref")
+                                          seq_lens, layer, mode="ref")
 
     def forward_extend(self, q, kpool, vpool, block_tables, seq_lens,
-                       num_new):
+                       num_new, layer):
         return ops.paged_attention_extend(q, kpool, vpool, block_tables,
-                                          seq_lens, num_new, mode="ref")
+                                          seq_lens, num_new, layer,
+                                          mode="ref")
 
 
 def _shard_mapped(fn, q, kpool, vpool, *scalars):
     """Run a paged-attention kernel shard-local over the ``model`` axis: q
-    and the pools split on their head axis (dim 2), block tables / seq_lens
-    / num_new replicated. ``validate_mesh`` has already refused head counts
-    the axis does not divide."""
+    split on its head axis and the pools on their Hkv*hd row (whole kv
+    heads per device), block tables / seq_lens / num_new / the layer index
+    replicated. ``validate_mesh`` has already refused head counts the axis
+    does not divide."""
     heads = P(None, None, "model", None)
-    return model_sharded(fn, (heads, heads, heads) + (P(),) * len(scalars),
+    pools = P(None, None, None, "model")
+    return model_sharded(fn, (heads, pools, pools) + (P(),) * len(scalars),
                          heads)(q, kpool, vpool, *scalars)
 
 
@@ -109,19 +115,20 @@ class PallasAttentionBackend(AttentionBackend):
                 f"platform {platform!r} — use attn_backend='interpret' "
                 f"(same kernels, Pallas interpret mode) or 'ref' on CPU")
 
-    def forward_decode(self, q, kpool, vpool, block_tables, seq_lens):
-        def call(q, kpool, vpool, bt, sl):
-            return ops.paged_attention_decode(q, kpool, vpool, bt, sl,
-                                              mode=self.kernel_mode)
-        return _shard_mapped(call, q, kpool, vpool, block_tables, seq_lens)
-
-    def forward_extend(self, q, kpool, vpool, block_tables, seq_lens,
-                       num_new):
-        def call(q, kpool, vpool, bt, sl, nn):
-            return ops.paged_attention_extend(q, kpool, vpool, bt, sl, nn,
+    def forward_decode(self, q, kpool, vpool, block_tables, seq_lens, layer):
+        def call(q, kpool, vpool, bt, sl, layer):
+            return ops.paged_attention_decode(q, kpool, vpool, bt, sl, layer,
                                               mode=self.kernel_mode)
         return _shard_mapped(call, q, kpool, vpool, block_tables, seq_lens,
-                             num_new)
+                             layer)
+
+    def forward_extend(self, q, kpool, vpool, block_tables, seq_lens,
+                       num_new, layer):
+        def call(q, kpool, vpool, bt, sl, nn, layer):
+            return ops.paged_attention_extend(q, kpool, vpool, bt, sl, nn,
+                                              layer, mode=self.kernel_mode)
+        return _shard_mapped(call, q, kpool, vpool, block_tables, seq_lens,
+                             num_new, layer)
 
 
 class InterpretAttentionBackend(PallasAttentionBackend):

@@ -247,7 +247,7 @@ class HostRoundtripTransport(Transport):
         src_ids = np.asarray(src_blocks, np.int32)
         payload = {}
         for k, pool in src_kv.pools.items():
-            arr = np.asarray(pool[:, src_ids])       # (L, n, bs, Hkv, hd)
+            arr = np.asarray(pool[:, src_ids])       # (L, n, bs, Hkv*hd)
             payload[k] = (arr.tobytes(), arr.shape, str(arr.dtype))
         # -- everything below this line could run in another process --
         dst_ids = np.asarray(dst_blocks, np.int32)

@@ -3,9 +3,11 @@
 Two layers of guarantees:
 
 1. Kernel semantics (interpret mode, CPU): the fused decode and
-   chunk-append kernels match the plain-jnp oracles in ``kernels.ref`` to
-   float tolerance over fuzzed block tables, ragged seq_lens, GQA ratios,
-   ``write_valid`` masks, and ``num_new`` padded tails.
+   chunk-append kernels, handed the whole (L, N, bs, Hkv*hd) pools and a
+   layer index, match the plain-jnp oracles in ``kernels.ref`` on that
+   layer's (N, bs, Hkv, hd) pages to float tolerance over fuzzed layers, block tables, ragged
+   seq_lens, GQA ratios, ``write_valid`` masks, and ``num_new`` padded
+   tails.
 2. Serving semantics: greedy tokens through the full ``ServingEngine`` are
    IDENTICAL between the ``ref`` backend (gather-pages SDPA, the numerics
    reference) and the kernel backend (interpret mode here; the compiled
@@ -30,14 +32,23 @@ from repro.serving.attention import (ATTN_BACKENDS, AttentionBackend,
 BS = 4
 
 
+LAYERS = 3
+
+
 def _random_paged(rng, b, hkv, g, hd, bs, width):
-    """Random pools + a valid random block table (block 0 = null)."""
+    """Random (LAYERS, N, bs, Hkv*hd) pools, a valid random block table
+    (block 0 = null) and a random layer to read."""
     n = 1 + b * width
-    kpool = jnp.asarray(rng.randn(n, bs, hkv, hd), jnp.float32)
-    vpool = jnp.asarray(rng.randn(n, bs, hkv, hd), jnp.float32)
+    kpool = jnp.asarray(rng.randn(LAYERS, n, bs, hkv * hd), jnp.float32)
+    vpool = jnp.asarray(rng.randn(LAYERS, n, bs, hkv * hd), jnp.float32)
     perm = rng.permutation(np.arange(1, n))
     bt = jnp.asarray(perm[:b * width].reshape(b, width).astype(np.int32))
-    return kpool, vpool, bt
+    return kpool, vpool, bt, jnp.int32(rng.randint(LAYERS))
+
+
+def _pages(pool, layer, hd):
+    """Layer ``layer`` of a pool as the oracles' (N, bs, Hkv, hd) pages."""
+    return pool[layer].reshape(*pool.shape[1:3], -1, hd)
 
 
 # --------------------------------------------------------------------------- #
@@ -53,11 +64,12 @@ def test_decode_kernel_fuzz_vs_ref():
         hd = int(rng.choice([8, 16]))
         bs = int(rng.choice([2, 4, 8]))
         width = rng.randint(1, 7)
-        kpool, vpool, bt = _random_paged(rng, b, hkv, g, hd, bs, width)
+        kpool, vpool, bt, l = _random_paged(rng, b, hkv, g, hd, bs, width)
         sl = jnp.asarray(rng.randint(0, width * bs, size=b), jnp.int32)
         q = jnp.asarray(rng.randn(b, 1, hkv * g, hd), jnp.float32)
-        o_ref = ref.paged_attention_decode(q, kpool, vpool, bt, sl)
-        o_k = ops.paged_attention_decode(q, kpool, vpool, bt, sl,
+        o_ref = ref.paged_attention_decode(q, _pages(kpool, l, hd),
+                                           _pages(vpool, l, hd), bt, sl)
+        o_k = ops.paged_attention_decode(q, kpool, vpool, bt, sl, l,
                                          mode="interpret")
         assert float(jnp.abs(o_ref - o_k).max()) < 2e-5
 
@@ -68,11 +80,12 @@ def test_decode_kernel_ragged_and_boundary_seq_lens():
     table — the liveness predicate's edges."""
     rng = np.random.RandomState(1)
     b, hkv, g, hd, bs, width = 5, 2, 2, 16, 4, 4
-    kpool, vpool, bt = _random_paged(rng, b, hkv, g, hd, bs, width)
+    kpool, vpool, bt, l = _random_paged(rng, b, hkv, g, hd, bs, width)
     sl = jnp.asarray([0, bs - 1, bs, 2 * bs, width * bs - 1], jnp.int32)
     q = jnp.asarray(rng.randn(b, 1, hkv * g, hd), jnp.float32)
-    o_ref = ref.paged_attention_decode(q, kpool, vpool, bt, sl)
-    o_k = ops.paged_attention_decode(q, kpool, vpool, bt, sl,
+    o_ref = ref.paged_attention_decode(q, _pages(kpool, l, hd),
+                                       _pages(vpool, l, hd), bt, sl)
+    o_k = ops.paged_attention_decode(q, kpool, vpool, bt, sl, l,
                                      mode="interpret")
     assert float(jnp.abs(o_ref - o_k).max()) < 2e-5
 
@@ -87,12 +100,13 @@ def test_chunk_kernel_fuzz_vs_ref():
         bs = int(rng.choice([2, 4]))
         s = int(rng.choice([2, 4, 8]))
         width = rng.randint(max(1, -(-s // bs)) + 1, 8)
-        kpool, vpool, bt = _random_paged(rng, b, hkv, g, hd, bs, width)
+        kpool, vpool, bt, l = _random_paged(rng, b, hkv, g, hd, bs, width)
         sl = jnp.asarray(rng.randint(0, width * bs - s, size=b), jnp.int32)
         nn = jnp.asarray(rng.randint(0, s + 1, size=b), jnp.int32)
         q = jnp.asarray(rng.randn(b, s, hkv * g, hd), jnp.float32)
-        o_ref = ref.paged_attention_extend(q, kpool, vpool, bt, sl, nn)
-        o_k = ops.paged_attention_extend(q, kpool, vpool, bt, sl, nn,
+        o_ref = ref.paged_attention_extend(q, _pages(kpool, l, hd),
+                                           _pages(vpool, l, hd), bt, sl, nn)
+        o_k = ops.paged_attention_extend(q, kpool, vpool, bt, sl, nn, l,
                                          mode="interpret")
         # rows at or past num_new are padding — garbage in both paths
         valid = (jnp.arange(s)[None, :] < nn[:, None])[:, :, None, None]
@@ -103,12 +117,12 @@ def test_chunk_kernel_zero_num_new_row_is_finite():
     """A padded batch row (num_new == 0, all-null table) has no live pages:
     the kernel must emit zeros, never NaN (the engine discards the row)."""
     rng = np.random.RandomState(3)
-    kpool, vpool, _ = _random_paged(rng, 1, 2, 2, 16, 4, 3)
+    kpool, vpool, _, l = _random_paged(rng, 1, 2, 2, 16, 4, 3)
     bt = jnp.zeros((1, 3), jnp.int32)
     q = jnp.asarray(rng.randn(1, 4, 4, 16), jnp.float32)
     out = ops.paged_attention_extend(
         q, kpool, vpool, bt, jnp.zeros((1,), jnp.int32),
-        jnp.zeros((1,), jnp.int32), mode="interpret")
+        jnp.zeros((1,), jnp.int32), l, mode="interpret")
     assert bool(jnp.isfinite(out).all())
     assert float(jnp.abs(out).max()) == 0.0
 
@@ -119,13 +133,13 @@ def test_gqa_head_group_mapping_matches_repeat_kv():
     check the per-head outputs against a per-head dense reference."""
     rng = np.random.RandomState(4)
     b, hkv, g, hd, bs, width = 2, 4, 2, 8, 4, 3
-    kpool, vpool, bt = _random_paged(rng, b, hkv, g, hd, bs, width)
+    kpool, vpool, bt, l = _random_paged(rng, b, hkv, g, hd, bs, width)
     sl = jnp.asarray([5, 9], jnp.int32)
     q = jnp.asarray(rng.randn(b, 1, hkv * g, hd), jnp.float32)
-    out = ops.paged_attention_decode(q, kpool, vpool, bt, sl,
+    out = ops.paged_attention_decode(q, kpool, vpool, bt, sl, l,
                                      mode="interpret")
-    kf = kpool[bt].reshape(b, -1, hkv, hd)
-    vf = vpool[bt].reshape(b, -1, hkv, hd)
+    kf = kpool[l, bt].reshape(b, -1, hkv, hd)
+    vf = vpool[l, bt].reshape(b, -1, hkv, hd)
     kpos = jnp.arange(kf.shape[1])
     scale = 1.0 / (hd ** 0.5)
     for h in range(hkv * g):

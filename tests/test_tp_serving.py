@@ -44,19 +44,21 @@ def _run(script: str, timeout=560) -> str:
 # --------------------------------------------------------------------------- #
 
 def test_paged_pool_spec_shards_kv_heads_only():
-    """kpool/vpool split ONLY the kv-head axis; the block axis (host-side
-    free-list ids) and intra-block offset stay whole even with a data axis
-    on the mesh (the generic batch-dim rule must not touch dim 1)."""
+    """kpool/vpool split ONLY the Hkv*hd row, into whole kv heads; the
+    block axis (host-side free-list ids) and intra-block offset stay whole
+    even with a data axis on the mesh (the generic batch-dim rule must not
+    touch dim 1)."""
     cfg = get_config("paper-0.5b").reduced()
-    shape = (cfg.num_layers, 16, 4, cfg.num_kv_heads, cfg.resolved_head_dim)
+    shape = (cfg.num_layers, 16, 4,
+             cfg.num_kv_heads * cfg.resolved_head_dim)
     mesh = AbstractMesh((2, 2), ("data", "model"))
     for name in ("kpool", "vpool"):
         spec = cache_spec(name, shape, cfg, mesh)
-        assert tuple(spec) == (None, None, None, "model", None), (name, spec)
+        assert tuple(spec) == (None, None, None, "model"), (name, spec)
     # non-divisible kv heads -> fully replicated, never a seq-dim fallback
     import dataclasses
     cfg3 = dataclasses.replace(cfg, num_kv_heads=3)
-    spec = cache_spec("kpool", (2, 16, 4, 3, 16), cfg3, mesh)
+    spec = cache_spec("kpool", (2, 16, 4, 3 * 16), cfg3, mesh)
     assert "model" not in tuple(spec) and tuple(spec)[1] is None
 
 
@@ -66,7 +68,7 @@ def test_make_paged_pool_shardings_specs():
     sh = make_paged_pool_shardings(cfg, mesh, num_blocks=8, block_size=4)
     assert set(sh) == {"kpool", "vpool"}
     for s in sh.values():
-        assert tuple(s.spec) == (None, None, None, "model", None)
+        assert tuple(s.spec) == (None, None, None, "model")
 
 
 def test_current_mesh_one_path_with_and_without_context():
@@ -282,7 +284,7 @@ for n in ('kpool', 'vpool'):
     b = np.asarray(kvs['tp2'].pools[n])
     np.testing.assert_array_equal(a, b)
 sh = kvs['tp2'].pools['kpool'].sharding
-assert tuple(sh.spec) == (None, None, None, 'model', None), sh
+assert tuple(sh.spec) == (None, None, None, 'model'), sh
 print('COW_SHARDED_OK')
 """)
     assert "COW_SHARDED_OK" in out
