@@ -44,6 +44,7 @@ flush. Both are also ``jax.profiler`` annotations of the same names.
 from __future__ import annotations
 
 import contextlib
+import gc
 import json
 import select
 import socket
@@ -83,6 +84,7 @@ class ServingServer:
         # that finishes so load balancers don't route to a cold process
         self._warmup = bool(warmup)
         self._ready = threading.Event()
+        self._frozen = False                  # set-up heap frozen (warmup)
         if not self._warmup:
             self._ready.set()
         engine.on_new_work = self._work.set
@@ -298,6 +300,13 @@ class ServingServer:
     def _engine_loop(self) -> None:
         if self._warmup and not self._ready.is_set():
             self.engine.warmup()                # precompile the bucket grid
+            # what set-up built (modules, traced and compiled programs, the
+            # engine) lives as long as the server: park it outside the
+            # collector, so a full collection while serving walks only what
+            # serving allocated, not a heap that takes it hundreds of ms
+            gc.collect()
+            gc.freeze()
+            self._frozen = True
             self._ready.set()
         while not self._stop.is_set():
             if self.engine.has_unfinished():
@@ -368,6 +377,9 @@ class ServingServer:
         flush = getattr(self.engine, "flush", None)
         if flush is not None:
             flush()          # drain any pipelined in-flight step (no-op sync)
+        if self._frozen:
+            gc.unfreeze()    # the set-up heap is collectable again
+            self._frozen = False
 
     # ---- introspection -----------------------------------------------------
 

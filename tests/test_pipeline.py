@@ -437,6 +437,27 @@ def test_server_without_warmup_ready_immediately(dense_model):
     server.shutdown()
 
 
+def test_server_warmup_freezes_setup_heap_until_shutdown(dense_model):
+    """Once warm, what set-up built (the engine among it) is out of the
+    collector's full passes; shutdown hands it back."""
+    import gc
+    from repro.serving.server import ServingServer
+    params, cfg = dense_model
+    engine = ServingEngine(params, cfg, block_size=BS, max_batch=2,
+                           max_seq_len=16, prefill_chunk=8,
+                           min_prefill_bucket=4, pipeline=True)
+    server = ServingServer(engine, port=0, warmup=True)
+    try:
+        server.start()
+        assert server.wait_ready(timeout=300)
+        assert gc.get_freeze_count() > 0
+        assert not any(o is engine for o in gc.get_objects())
+    finally:
+        server.shutdown()
+    assert gc.get_freeze_count() == 0
+    assert any(o is engine for o in gc.get_objects())
+
+
 # --------------------------------------------------------------------------- #
 # tp=2: pipelined == synchronous == unsharded (subprocess, fake devices)
 # --------------------------------------------------------------------------- #
