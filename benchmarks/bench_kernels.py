@@ -56,9 +56,9 @@ def run():
     from repro.kernels.twell_pack import twell_gate_matmul_pallas
     sm = jax.random.normal(key, (32, 64)) * 0.5
     wgs = jax.random.normal(key, (64, 256)) * 0.05 - 0.02
-    t0 = timeit(lambda: twell_gate_matmul_pallas(sm, wgs, 256, 8, "relu",
-                                                 bm=32, bk=64), iters=3,
-                warmup=1)
+    t0 = timeit(lambda: twell_gate_matmul_pallas(sm, wgs[None], 0, 256, 8,
+                                                 "relu", bm=32, bk=64),
+                iters=3, warmup=1)
     emit("kernel_pallas_interpret_twell_pack", t0, "interpret-mode")
 
 

@@ -150,8 +150,8 @@ def check_ffn_kernels(cfg, key, checks: Checks) -> None:
         x, wg, wu, wd = ternary_ffn_inputs(jax.random.fold_in(key, m), m,
                                            cfg.d_model, cfg.d_ff, dtype)
         name = f"M={m} K={cfg.d_model} N={cfg.d_ff} T={tile} C={comp}"
-        vals, idx, nnz = twell_gate_matmul_pallas(x, wg, tile, comp, act,
-                                                  interpret=False)
+        vals, idx, nnz = twell_gate_matmul_pallas(x, wg[None], 0, tile, comp,
+                                                  act, interpret=False)
         tw = ref.twell_gate_matmul(x, wg, tile, comp, act)
         exact = (np.array_equal(np.minimum(np.asarray(nnz), tc),
                                 np.asarray(tw.nnz))
@@ -161,18 +161,20 @@ def check_ffn_kernels(cfg, key, checks: Checks) -> None:
                      exact and err <= KERNEL_TOL,
                      f"counts and indices {'equal' if exact else 'DIFFER'}, "
                      f"value error {err:.3e} (tolerance {KERNEL_TOL})")
-        y = twell_fused_ffn_pallas(tw.values, tw.indices, tw.nnz, x, wu, wd,
-                                   tile, interpret=False)
+        y = twell_fused_ffn_pallas(tw.values, tw.indices, tw.nnz, x,
+                                   wu[None], wd[None], 0, tile,
+                                   interpret=False)
         err = rel_err(y, ref.twell_fused_ffn(x, tw, wu, wd))
         checks.check("kernels", f"twell_fused_ffn {name}", err <= KERNEL_TOL,
                      f"error {err:.3e} (tolerance {KERNEL_TOL})")
         tu = ref.twell_gate_matmul(x, wu, tile, comp, act)   # non-gated
-        y = twell_down_proj_pallas(tu.values, tu.indices, tu.nnz, wd, tile,
-                                   interpret=False)
+        y = twell_down_proj_pallas(tu.values, tu.indices, tu.nnz, wd[None],
+                                   0, tile, interpret=False)
         err = rel_err(y, ref.twell_down_proj(tu, wd))
         checks.check("kernels", f"twell_down_proj {name}", err <= KERNEL_TOL,
                      f"error {err:.3e} (tolerance {KERNEL_TOL})")
-        y, h = tile_skip_ffn_pallas(x, wg, wu, wd, tile, act, interpret=False)
+        y, h = tile_skip_ffn_pallas(x, wg[None], wu[None], wd[None], 0, tile,
+                                    act, interpret=False)
         y_ref, h_ref = ref.tile_skip_ffn(x, wg, wu, wd, tile, act)
         err = max(rel_err(y, y_ref), rel_err(h, h_ref))
         checks.check("kernels", f"tile_skip_ffn {name}", err <= KERNEL_TOL,
@@ -252,7 +254,7 @@ def overflow_reading(cfg, params, prompts, label: str) -> None:
     blk = params["blocks"]
     x = norm_apply(cfg.norm, {k: v[0] for k, v in blk["ln2"].items()},
                    embed_lookup(params["embed"], toks))
-    _, _, nnz = twell_gate_matmul_pallas(x, blk["ffn"]["wg"][0],
+    _, _, nnz = twell_gate_matmul_pallas(x, blk["ffn"]["wg"], 0,
                                          sp.twell_tile, sp.twell_c,
                                          sp.activation, interpret=False)
     nnz = np.asarray(nnz)

@@ -15,6 +15,14 @@ One module, four execution strategies (``SparsityConfig.ffn_impl``):
                  pattern-only backward and L1 gradient injection. This is the
                  peak-memory reduction of Table 1, natively in JAX.
 
+The weights are stacked over layers (``wg`` / ``wu`` ``(L, d_model, d_ff)``,
+``wd`` ``(L, d_ff, d_model)``, as the model's layer scan holds them) and an
+int32 ``layer`` picks one: the kernel-backed impls hand the stacked arrays
+and the layer to the kernels, which read that layer's tiles in place, so a
+serving step never copies a layer's FFN weights out of the stack. ``apply``
+called without a layer takes one layer's matrices and stacks them as a
+stack of one.
+
 All impls return ``(y, aux)`` with ``aux = {l1, nnz_mean, nnz_max,
 neuron_active, tile_frac}`` feeding Eq. 2, the Sec. 4.3 instrumentation,
 and the observability cost model (``repro.observability.accounting``).
@@ -76,8 +84,14 @@ def _aux_from_h(h: jax.Array, tile: int) -> Dict[str, jax.Array]:
 # dense (paper-faithful math; also the tile_skip CPU path)
 # --------------------------------------------------------------------------- #
 
-def _dense_apply(params, x, scfg: SparsityConfig, gated: bool):
+def _layer(params, layer):
+    """One layer's matrices of the stacked FFN weights."""
+    return {k: w[layer] for k, w in params.items()}
+
+
+def _dense_apply(params, x, scfg: SparsityConfig, gated: bool, layer):
     from repro.distributed.sharding import shard_act
+    params = _layer(params, layer)
     act = activation(scfg.activation if scfg.enabled else "silu")
     if gated:
         h = (x @ params["wu"]) * act(x @ params["wg"])
@@ -95,21 +109,23 @@ def _dense_apply(params, x, scfg: SparsityConfig, gated: bool):
 # TwELL gather (Eq. 3) — inference
 # --------------------------------------------------------------------------- #
 
-def _twell_apply(params, x, scfg: SparsityConfig, gated: bool):
+def _twell_apply(params, x, scfg: SparsityConfig, gated: bool, layer):
     from repro.kernels import ops as kops
     if gated:
         y, tw = kops.twell_ffn(x, params["wg"], params["wu"], params["wd"],
-                               scfg.twell_tile, scfg.twell_c, scfg.activation)
+                               layer, scfg.twell_tile, scfg.twell_c,
+                               scfg.activation)
         # Eq. 2's L1 is over h = h_u * h_g: recover |h| on the pattern via
-        # the same gathered h_u elements the fused kernel computes (Eq. 3)
+        # the same gathered h_u elements the fused kernel computes (Eq. 3);
+        # one gather of the pattern's W_u columns out of the stack
         tc = tw.slot_width
         slot = jnp.arange(tw.values.shape[1], dtype=jnp.int32) % tc
         valid = slot[None, :] < jnp.repeat(tw.nnz, tc, axis=-1)
-        hu_p = jnp.einsum("mk,mck->mc", x, params["wu"].T[tw.indices])
+        hu_p = jnp.einsum("mk,mck->mc", x, params["wu"][layer, :, tw.indices])
         h_abs = jnp.abs(jnp.where(valid, tw.values * hu_p, 0)
                         .astype(jnp.float32))
     else:
-        y, tw = kops.twell_ffn_nongated(x, params["wu"], params["wd"],
+        y, tw = kops.twell_ffn_nongated(x, params["wu"], params["wd"], layer,
                                         scfg.twell_tile, scfg.twell_c,
                                         scfg.activation)
         h_abs = jnp.abs(tw.values.astype(jnp.float32))
@@ -130,12 +146,12 @@ def _twell_apply(params, x, scfg: SparsityConfig, gated: bool):
 # tile_skip — TPU block-sparse kernel, dense math on CPU
 # --------------------------------------------------------------------------- #
 
-def _tile_skip_apply(params, x, scfg: SparsityConfig, gated: bool):
+def _tile_skip_apply(params, x, scfg: SparsityConfig, gated: bool, layer):
     from repro.kernels import ops as kops
     if not gated:
-        return _dense_apply(params, x, scfg, gated)
+        return _dense_apply(params, x, scfg, gated, layer)
     y, h = kops.tile_skip_ffn(x, params["wg"], params["wu"], params["wd"],
-                              scfg.twell_tile, scfg.activation,
+                              layer, scfg.twell_tile, scfg.activation,
                               threshold=scfg.tile_skip_threshold)
     return y, _aux_from_h(h, scfg.twell_tile)
 
@@ -292,7 +308,8 @@ def _hybrid_nongated_bwd(ell_width, num_dense_rows, act_name, res, cts):
 _hybrid_nongated.defvjp(_hybrid_nongated_fwd, _hybrid_nongated_bwd)
 
 
-def _hybrid_apply(params, x, scfg: SparsityConfig, gated: bool):
+def _hybrid_apply(params, x, scfg: SparsityConfig, gated: bool, layer):
+    params = _layer(params, layer)
     m = x.shape[0]
     md = max(1, int(m * scfg.dense_backup_frac))
     if gated:
@@ -325,10 +342,16 @@ _IMPLS = {
 
 
 def apply(params: Dict[str, jax.Array], x: jax.Array, scfg: SparsityConfig,
-          gated: bool) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """x: (..., d_model) -> (..., d_model), plus sparsity aux."""
+          gated: bool, layer=None) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """x: (..., d_model) -> (..., d_model), plus sparsity aux.
+
+    ``params`` hold the weights stacked over layers and ``layer`` (an int32
+    scalar) picks one; without a layer they are one layer's matrices, which
+    go on as a stack of one at layer 0."""
+    if layer is None:
+        params, layer = jax.tree.map(lambda w: w[None], params), 0
     lead = x.shape[:-1]
     x2 = x.reshape(-1, x.shape[-1])
     impl = scfg.ffn_impl if scfg.enabled else "dense"
-    y, aux = _IMPLS[impl](params, x2, scfg, gated)
+    y, aux = _IMPLS[impl](params, x2, scfg, gated, layer)
     return y.reshape(*lead, -1), aux

@@ -5,6 +5,12 @@ back to the numerically-identical jnp references in ``kernels.ref``; the
 tests pin the kernels to those references by calling them in Pallas
 interpret mode (the paged-attention wrappers take an explicit ``mode``).
 
+The FFN wrappers take the weights stacked over layers — W_g / W_u
+``(L, K, N)``, W_d ``(L, N, K)`` — and an int32 ``layer``: the kernels read
+that layer's tiles out of the stacked arrays, so the layer scan never
+slices them. A caller holding one layer's matrices passes ``w[None]`` and
+layer 0.
+
 Under a tensor-parallel mesh the FFN kernels run shard-locally over the
 ``model`` axis (Pallas calls are opaque to GSPMD): each device packs and
 projects its own d_ff slice, whose width the serving backends require to be
@@ -44,55 +50,64 @@ def _twell_acts(vals, idx, nnz, tile: int, compression: int, n: int):
                            jnp.any(nnz > tc), tile, compression, n)
 
 
-def twell_ffn(x, wg, wu, wd, tile: int, compression: int, act: str = "relu"):
-    """Gated TwELL FFN (Algorithm 1 pack + Eq. 3 fused up/down):
-    x (M, K) -> (y (M, K) in x.dtype, the gate's TwellActs)."""
+# shard specs of the stacked weights: W_g / W_u split their d_ff columns,
+# W_d its d_ff rows, over ``model``; the layer index is replicated
+_COLS, _ROWS = P(None, None, "model"), P(None, "model", None)
+
+
+def twell_ffn(x, wg, wu, wd, layer, tile: int, compression: int,
+              act: str = "relu"):
+    """Gated TwELL FFN (Algorithm 1 pack + Eq. 3 fused up/down) of layer
+    ``layer`` of the stacked weights: x (M, K) -> (y (M, K) in x.dtype, the
+    gate's TwellActs)."""
     if kernel_mode() == "ref":
-        tw = ref.twell_gate_matmul(x, wg, tile, compression, act)
-        return ref.twell_fused_ffn(x, tw, wu, wd), tw
+        tw = ref.twell_gate_matmul(x, wg[layer], tile, compression, act)
+        return ref.twell_fused_ffn(x, tw, wu[layer], wd[layer]), tw
     from repro.kernels.sparse_ffn import twell_fused_ffn_pallas
     from repro.kernels.twell_pack import twell_gate_matmul_pallas
 
     tp = _tp()
 
-    def local(x, wg, wu, wd):
+    def local(x, wg, wu, wd, layer):
         vals, idx, nnz = twell_gate_matmul_pallas(
-            x, wg, tile, compression, act, interpret=False)
-        y = twell_fused_ffn_pallas(vals, idx, nnz, x, wu, wd, tile,
+            x, wg, layer, tile, compression, act, interpret=False)
+        y = twell_fused_ffn_pallas(vals, idx, nnz, x, wu, wd, layer, tile,
                                    interpret=False)
         return _shard_outputs(tp, y, vals, idx, nnz, tile, compression)
 
     col = P(None, "model")
     y, vals, idx, nnz = model_sharded(
-        local, (P(), col, col, P("model", None)), (P(), col, col, col))(
-            x, wg, wu, wd)
+        local, (P(), _COLS, _COLS, _ROWS, P()), (P(), col, col, col))(
+            x, wg, wu, wd, jnp.asarray(layer, jnp.int32))
     return y.astype(x.dtype), _twell_acts(vals, idx, nnz, tile, compression,
-                                          wg.shape[1])
+                                          wg.shape[2])
 
 
-def twell_ffn_nongated(x, wu, wd, tile: int, compression: int,
+def twell_ffn_nongated(x, wu, wd, layer, tile: int, compression: int,
                        act: str = "relu"):
-    """Non-gated TwELL FFN (App. C.2): the up projection packs, the down
-    projection consumes the pattern. -> (y, TwellActs)."""
+    """Non-gated TwELL FFN (App. C.2) of layer ``layer``: the up projection
+    packs, the down projection consumes the pattern. -> (y, TwellActs)."""
     if kernel_mode() == "ref":
-        tw = ref.twell_gate_matmul(x, wu, tile, compression, act)
-        return ref.twell_down_proj(tw, wd), tw
+        tw = ref.twell_gate_matmul(x, wu[layer], tile, compression, act)
+        return ref.twell_down_proj(tw, wd[layer]), tw
     from repro.kernels.sparse_ffn import twell_down_proj_pallas
     from repro.kernels.twell_pack import twell_gate_matmul_pallas
 
     tp = _tp()
 
-    def local(x, wu, wd):
+    def local(x, wu, wd, layer):
         vals, idx, nnz = twell_gate_matmul_pallas(
-            x, wu, tile, compression, act, interpret=False)
-        y = twell_down_proj_pallas(vals, idx, nnz, wd, tile, interpret=False)
+            x, wu, layer, tile, compression, act, interpret=False)
+        y = twell_down_proj_pallas(vals, idx, nnz, wd, layer, tile,
+                                   interpret=False)
         return _shard_outputs(tp, y, vals, idx, nnz, tile, compression)
 
     col = P(None, "model")
     y, vals, idx, nnz = model_sharded(
-        local, (P(), col, P("model", None)), (P(), col, col, col))(x, wu, wd)
+        local, (P(), _COLS, _ROWS, P()), (P(), col, col, col))(
+            x, wu, wd, jnp.asarray(layer, jnp.int32))
     return y.astype(wd.dtype), _twell_acts(vals, idx, nnz, tile, compression,
-                                           wu.shape[1])
+                                           wu.shape[2])
 
 
 def _shard_outputs(tp: int, y, vals, idx, nnz, tile: int,
@@ -108,25 +123,28 @@ def _shard_outputs(tp: int, y, vals, idx, nnz, tile: int,
     return jax.lax.psum(y, "model"), vals, idx, nnz
 
 
-def tile_skip_ffn(x, wg, wu, wd, tile: int, act: str = "relu",
+def tile_skip_ffn(x, wg, wu, wd, layer, tile: int, act: str = "relu",
                   threshold: float = 0.0):
+    """Gated FFN of layer ``layer`` of the stacked weights with (row x
+    hidden-tile) block skipping -> (y in x.dtype, dense h)."""
     # Thresholded (lossy) tile dropping runs through the reference math for
     # now: the Pallas harvest kernel's skip predicate is `tile all-zero`;
     # folding the |hg|<=threshold predicate into it is TPU follow-up work.
     if kernel_mode() == "ref" or threshold > 0.0:
-        return ref.tile_skip_ffn(x, wg, wu, wd, tile, act, threshold)
+        return ref.tile_skip_ffn(x, wg[layer], wu[layer], wd[layer], tile,
+                                 act, threshold)
     from repro.kernels.sparse_ffn import tile_skip_ffn_pallas
 
     tp = _tp()
 
-    def local(x, wg, wu, wd):
-        y, h = tile_skip_ffn_pallas(x, wg, wu, wd, tile, act,
+    def local(x, wg, wu, wd, layer):
+        y, h = tile_skip_ffn_pallas(x, wg, wu, wd, layer, tile, act,
                                     interpret=False)
         return (jax.lax.psum(y, "model") if tp > 1 else y), h
 
-    col = P(None, "model")
-    y, h = model_sharded(local, (P(), col, col, P("model", None)),
-                         (P(), col))(x, wg, wu, wd)
+    y, h = model_sharded(local, (P(), _COLS, _COLS, _ROWS, P()),
+                         (P(), P(None, "model")))(
+                             x, wg, wu, wd, jnp.asarray(layer, jnp.int32))
     return y.astype(x.dtype), h
 
 

@@ -9,7 +9,13 @@ of dead tiles still run.
 Grid: (M/bm, N/T); full-K blocks (VMEM budget: (bm + 2T) * K * bytes). The
 packed operands arrive in the kernel layout of ``kernels/twell_pack.py``
 (tile-major values/indices, a (bm, N/T) count row block); the jitted
-wrappers take the public ``TwellActs`` layout and convert.
+wrappers take the public ``TwellActs`` layout and convert. The weights
+arrive stacked over layers — W_u / W_g ``(L, K, N)``, W_d ``(L, N, K)`` —
+with the layer as the one scalar-prefetch operand
+(``twell_pack.layer_operand``): tile ``j`` of W_u is the ``(K, T)`` block
+at ``(layer, 0, j)`` and of W_d the ``(T, K)`` block at ``(layer, j, 0)``,
+DMA'd straight out of the stacked arrays. A caller holding one layer's
+matrices passes ``w[None]`` and layer 0.
 """
 from __future__ import annotations
 
@@ -18,8 +24,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.twell_pack import column, to_tile_major
+from repro.kernels.twell_pack import column, layer_operand, to_tile_major
 
 
 def _unpack_tile(vals_ref, idx_ref, nnz_ref, j, tile: int):
@@ -39,8 +46,9 @@ def _unpack_tile(vals_ref, idx_ref, nnz_ref, j, tile: int):
     return dense
 
 
-def _kernel(vals_ref, idx_ref, nnz_ref, x_ref, wu_ref, wd_ref, y_ref, *,
-            tile: int):
+def _kernel(layer_ref, vals_ref, idx_ref, nnz_ref, x_ref, wu_ref, wd_ref,
+            y_ref, *, tile: int):
+    del layer_ref               # read by the index maps only
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -60,18 +68,36 @@ def _kernel(vals_ref, idx_ref, nnz_ref, x_ref, wu_ref, wd_ref, y_ref, *,
 def _packed_specs(bm: int, tc: int, nt: int):
     """BlockSpecs of the kernel-layout TwELL operands: tile-major
     values/indices (NT, M, tc) and the (bm, NT) count row block."""
-    return [pl.BlockSpec((pl.squeezed, bm, tc), lambda i, j: (j, i, 0)),
-            pl.BlockSpec((pl.squeezed, bm, tc), lambda i, j: (j, i, 0)),
-            pl.BlockSpec((bm, nt), lambda i, j: (i, 0))]
+    return [pl.BlockSpec((pl.squeezed, bm, tc), lambda i, j, _: (j, i, 0)),
+            pl.BlockSpec((pl.squeezed, bm, tc), lambda i, j, _: (j, i, 0)),
+            pl.BlockSpec((bm, nt), lambda i, j, _: (i, 0))]
+
+
+def _row_spec(bm: int, kdim: int):
+    """The (bm, K) row block of an activation input or of the output."""
+    return pl.BlockSpec((bm, kdim), lambda i, j, _: (i, 0))
+
+
+def _cols_spec(kdim: int, tile: int):
+    """Tile ``j`` of layer ``layer`` of a stacked (L, K, N) weight."""
+    return pl.BlockSpec((pl.squeezed, kdim, tile),
+                        lambda i, j, layer: (layer[0], 0, j))
+
+
+def _rows_spec(kdim: int, tile: int):
+    """Tile ``j`` of layer ``layer`` of a stacked (L, N, K) weight."""
+    return pl.BlockSpec((pl.squeezed, tile, kdim),
+                        lambda i, j, layer: (layer[0], j, 0))
 
 
 @functools.partial(jax.jit, static_argnames=("tile", "bm", "interpret"))
-def twell_fused_ffn_pallas(vals, idx, nnz, x, wu, wd, tile: int = 256,
+def twell_fused_ffn_pallas(vals, idx, nnz, x, wu, wd, layer, tile: int = 256,
                            bm: int = 128, interpret: bool = True):
-    """vals/idx: (M, N/C), nnz: (M, N/T), x: (M, K), wu: (K, N), wd: (N, K)
-    -> y: (M, K) f32 (cast by the caller)."""
+    """vals/idx: (M, N/C), nnz: (M, N/T), x: (M, K), wu: (L, K, N),
+    wd: (L, N, K), layer: int32 scalar -> y: (M, K) f32 (cast by the
+    caller)."""
     m, kdim = x.shape
-    n = wu.shape[1]
+    n = wu.shape[2]
     nt = n // tile
     tc = vals.shape[1] // nt
     bm = min(bm, m)
@@ -79,22 +105,26 @@ def twell_fused_ffn_pallas(vals, idx, nnz, x, wu, wd, tile: int = 256,
     kern = functools.partial(_kernel, tile=tile)
     return pl.pallas_call(
         kern,
-        grid=(m // bm, nt),
-        in_specs=_packed_specs(bm, tc, nt) + [
-            pl.BlockSpec((bm, kdim), lambda i, j: (i, 0)),
-            pl.BlockSpec((kdim, tile), lambda i, j: (0, j)),
-            pl.BlockSpec((tile, kdim), lambda i, j: (j, 0)),
-        ],
-        out_specs=pl.BlockSpec((bm, kdim), lambda i, j: (i, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(m // bm, nt),
+            in_specs=_packed_specs(bm, tc, nt) + [
+                _row_spec(bm, kdim), _cols_spec(kdim, tile),
+                _rows_spec(kdim, tile)],
+            out_specs=_row_spec(bm, kdim),
+        ),
         out_shape=jax.ShapeDtypeStruct((m, kdim), jnp.float32),
         interpret=interpret,
         name="twell_fused_ffn",
-    )(to_tile_major(vals, nt), to_tile_major(idx, nt), nnz, x, wu, wd)
+    )(layer_operand(layer), to_tile_major(vals, nt), to_tile_major(idx, nt),
+      nnz, x, wu, wd)
 
 
-def _down_kernel(vals_ref, idx_ref, nnz_ref, wd_ref, y_ref, *, tile: int):
+def _down_kernel(layer_ref, vals_ref, idx_ref, nnz_ref, wd_ref, y_ref, *,
+                 tile: int):
     """Non-gated variant (paper App. C.2, Listing 3): y = unpack(h) @ W_d
     with tile skipping — the up projection produced the TwELL pattern."""
+    del layer_ref
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -109,10 +139,12 @@ def _down_kernel(vals_ref, idx_ref, nnz_ref, wd_ref, y_ref, *, tile: int):
 
 
 @functools.partial(jax.jit, static_argnames=("tile", "bm", "interpret"))
-def twell_down_proj_pallas(vals, idx, nnz, wd, tile: int = 256,
+def twell_down_proj_pallas(vals, idx, nnz, wd, layer, tile: int = 256,
                            bm: int = 128, interpret: bool = True):
+    """vals/idx: (M, N/C), nnz: (M, N/T), wd: (L, N, K), layer: int32
+    scalar -> y: (M, K) f32."""
     m = vals.shape[0]
-    n, kdim = wd.shape
+    _, n, kdim = wd.shape
     nt = n // tile
     tc = vals.shape[1] // nt
     bm = min(bm, m)
@@ -120,21 +152,24 @@ def twell_down_proj_pallas(vals, idx, nnz, wd, tile: int = 256,
     kern = functools.partial(_down_kernel, tile=tile)
     return pl.pallas_call(
         kern,
-        grid=(m // bm, nt),
-        in_specs=_packed_specs(bm, tc, nt) + [
-            pl.BlockSpec((tile, kdim), lambda i, j: (j, 0)),
-        ],
-        out_specs=pl.BlockSpec((bm, kdim), lambda i, j: (i, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(m // bm, nt),
+            in_specs=_packed_specs(bm, tc, nt) + [_rows_spec(kdim, tile)],
+            out_specs=_row_spec(bm, kdim),
+        ),
         out_shape=jax.ShapeDtypeStruct((m, kdim), jnp.float32),
         interpret=interpret,
         name="twell_down_proj",
-    )(to_tile_major(vals, nt), to_tile_major(idx, nt), nnz, wd)
+    )(layer_operand(layer), to_tile_major(vals, nt), to_tile_major(idx, nt),
+      nnz, wd)
 
 
-def _kernel_gated_dense_gate(x_ref, wg_ref, wu_ref, wd_ref, y_ref, h_ref, *,
-                             act: str):
+def _kernel_gated_dense_gate(layer_ref, x_ref, wg_ref, wu_ref, wd_ref, y_ref,
+                             h_ref, *, act: str):
     """Single-kernel gated FFN with tile skipping decided *inside* (used when
     the gate matmul and the fused projections are fused end-to-end)."""
+    del layer_ref
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -160,30 +195,31 @@ def _kernel_gated_dense_gate(x_ref, wg_ref, wu_ref, wd_ref, y_ref, h_ref, *,
 
 
 @functools.partial(jax.jit, static_argnames=("tile", "act", "bm", "interpret"))
-def tile_skip_ffn_pallas(x, wg, wu, wd, tile: int = 256, act: str = "relu",
-                         bm: int = 128, interpret: bool = True):
+def tile_skip_ffn_pallas(x, wg, wu, wd, layer, tile: int = 256,
+                         act: str = "relu", bm: int = 128,
+                         interpret: bool = True):
     """End-to-end gated FFN with in-kernel tile skip; also emits dense h for
-    the sparsity statistics path. x: (M, K) -> (y (M, K) f32, h (M, N))."""
+    the sparsity statistics path. x: (M, K), wg/wu: (L, K, N), wd: (L, N, K),
+    layer: int32 scalar -> (y (M, K) f32, h (M, N))."""
     m, kdim = x.shape
-    n = wu.shape[1]
+    n = wu.shape[2]
     nt = n // tile
     bm = min(bm, m)
     assert m % bm == 0 and n % tile == 0
     kern = functools.partial(_kernel_gated_dense_gate, act=act)
     y, h = pl.pallas_call(
         kern,
-        grid=(m // bm, nt),
-        in_specs=[
-            pl.BlockSpec((bm, kdim), lambda i, j: (i, 0)),
-            pl.BlockSpec((kdim, tile), lambda i, j: (0, j)),
-            pl.BlockSpec((kdim, tile), lambda i, j: (0, j)),
-            pl.BlockSpec((tile, kdim), lambda i, j: (j, 0)),
-        ],
-        out_specs=[pl.BlockSpec((bm, kdim), lambda i, j: (i, 0)),
-                   pl.BlockSpec((bm, tile), lambda i, j: (i, j))],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(m // bm, nt),
+            in_specs=[_row_spec(bm, kdim), _cols_spec(kdim, tile),
+                      _cols_spec(kdim, tile), _rows_spec(kdim, tile)],
+            out_specs=[_row_spec(bm, kdim),
+                       pl.BlockSpec((bm, tile), lambda i, j, _: (i, j))],
+        ),
         out_shape=[jax.ShapeDtypeStruct((m, kdim), jnp.float32),
                    jax.ShapeDtypeStruct((m, n), x.dtype)],
         interpret=interpret,
         name="tile_skip_ffn",
-    )(x, wg, wu, wd)
+    )(layer_operand(layer), x, wg, wu, wd)
     return y, h

@@ -8,7 +8,12 @@ producing tile-locally packed values / global indices / counts in the same
 kernel — no second pass over dense data, no extra kernel launch.
 
 Grid: (M/bm, N/T, K/bk), K innermost (TPU sequential minor axis) with a VMEM
-f32 scratch accumulator; the epilogue fires on the last K step.
+f32 scratch accumulator; the epilogue fires on the last K step. The gate
+matrix arrives stacked over layers, ``(L, K, N)``, as the model keeps it,
+with the layer to read as a scalar-prefetch operand (``layer_operand``):
+each step DMAs the ``(bk, T)`` block at ``(layer, k, j)`` straight out of
+the stacked array, so no per-layer slice of the weights is ever made. A
+caller holding one layer's ``(K, N)`` matrix passes ``w[None]`` and layer 0.
 
 Kernel layout: values/indices are tile-major ``(N/T, M, T/C)`` so every
 block's last two dims are ``(bm, T/C)`` with ``T/C`` the whole last axis,
@@ -47,6 +52,12 @@ def from_tile_major(a):
     return a.transpose(1, 0, 2).reshape(m, nt * tc)
 
 
+def layer_operand(layer):
+    """The int32 layer index as the (1,) scalar-prefetch operand the
+    weights' index maps read."""
+    return jnp.asarray(layer, jnp.int32).reshape(1)
+
+
 def column(ref, j):
     """Column ``j`` of a (rows, cols) ref as a (rows, 1) f32 value — a
     masked lane reduction, so ``j`` may be a traced grid index."""
@@ -55,8 +66,9 @@ def column(ref, j):
     return jnp.sum(jnp.where(cols == j, a, 0.0), axis=1, keepdims=True)
 
 
-def _kernel(x_ref, w_ref, vals_ref, idx_ref, nnz_ref, acc_ref, *,
+def _kernel(layer_ref, x_ref, w_ref, vals_ref, idx_ref, nnz_ref, acc_ref, *,
             tile: int, tc: int, act: str):
+    del layer_ref               # read by the index maps only
     k = pl.program_id(2)
     nk = pl.num_programs(2)
     j = pl.program_id(1)        # read outside pl.when (interpret-mode req.)
@@ -100,17 +112,19 @@ def _kernel(x_ref, w_ref, vals_ref, idx_ref, nnz_ref, acc_ref, *,
 
 @functools.partial(jax.jit, static_argnames=("tile", "compression", "act",
                                              "bm", "bk", "interpret"))
-def twell_gate_matmul_pallas(x, w, tile: int = 256, compression: int = 8,
-                             act: str = "relu", bm: int = 128, bk: int = 512,
+def twell_gate_matmul_pallas(x, w, layer, tile: int = 256,
+                             compression: int = 8, act: str = "relu",
+                             bm: int = 128, bk: int = 512,
                              interpret: bool = True):
-    """x: (M, K), w: (K, N) -> (values (M, N/C), indices, nnz (M, N/T)).
+    """x: (M, K), w: (L, K, N), layer: int32 scalar -> (values (M, N/C),
+    indices, nnz (M, N/T)) of ``act(x @ w[layer])``.
 
     Note: counts are exact even when a tile overflows its T/C slots; values
     beyond the slot budget are dropped per the paper's overflow contract
     (App. B.2.1) — the caller compares nnz against T/C to raise the flag.
     """
     m, kdim = x.shape
-    _, n = w.shape
+    _, _, n = w.shape
     assert n % tile == 0 and tile % compression == 0
     bm = min(bm, m)
     bk = min(bk, kdim)
@@ -121,23 +135,29 @@ def twell_gate_matmul_pallas(x, w, tile: int = 256, compression: int = 8,
     kern = functools.partial(_kernel, tile=tile, tc=tc, act=act)
     vals, idx, nnz = pl.pallas_call(
         kern,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
-            pl.BlockSpec((bk, tile), lambda i, j, k: (k, j)),
-        ],
-        out_specs=[
-            pl.BlockSpec((pl.squeezed, bm, tc), lambda i, j, k: (j, i, 0)),
-            pl.BlockSpec((pl.squeezed, bm, tc), lambda i, j, k: (j, i, 0)),
-            pl.BlockSpec((bm, nt), lambda i, j, k: (i, 0)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((bm, bk), lambda i, j, k, _: (i, k)),
+                pl.BlockSpec((pl.squeezed, bk, tile),
+                             lambda i, j, k, layer: (layer[0], k, j)),
+            ],
+            out_specs=[
+                pl.BlockSpec((pl.squeezed, bm, tc),
+                             lambda i, j, k, _: (j, i, 0)),
+                pl.BlockSpec((pl.squeezed, bm, tc),
+                             lambda i, j, k, _: (j, i, 0)),
+                pl.BlockSpec((bm, nt), lambda i, j, k, _: (i, 0)),
+            ],
+            scratch_shapes=[pltpu.VMEM((bm, tile), jnp.float32)],
+        ),
         out_shape=[
             jax.ShapeDtypeStruct((nt, m, tc), x.dtype),
             jax.ShapeDtypeStruct((nt, m, tc), jnp.int32),
             jax.ShapeDtypeStruct((m, nt), jnp.int32),
         ],
-        scratch_shapes=[pltpu.VMEM((bm, tile), jnp.float32)],
         interpret=interpret,
         name="twell_gate_matmul",
-    )(x, w)
+    )(layer_operand(layer), x, w)
     return from_tile_major(vals), from_tile_major(idx), nnz

@@ -93,7 +93,9 @@ def _block_init(key, cfg, dtype, use_moe: bool, cross: bool = False) -> Dict:
 
 
 def _block_apply(p, x, cfg, positions, *, kind, use_moe, kv_x=None,
-                 cache=None):
+                 cache=None, ffn=None):
+    """One block. ``ffn``: ``(weights stacked over layers, layer)`` for the
+    FFN when ``p`` holds none of its own (the paged scan's blocks)."""
     mesh, dp = _dp()
     a, new_cache = attention(p["attn"], norm_apply(cfg.norm, p["ln1"], x), cfg,
                              positions=positions, kind=kind, kv_x=kv_x,
@@ -107,7 +109,8 @@ def _block_apply(p, x, cfg, positions, *, kind, use_moe, kv_x=None,
         y, aux = moe.moe_apply(p["moe"], h, cfg, cfg.sparsity, cfg.gated,
                                mesh, dp)
     else:
-        y, aux = sparse_ffn.apply(p["ffn"], h, cfg.sparsity, cfg.gated)
+        weights, layer = ffn if ffn is not None else (p["ffn"], None)
+        y, aux = sparse_ffn.apply(weights, h, cfg.sparsity, cfg.gated, layer)
     if "gate_ffn" in p:
         y = jnp.tanh(p["gate_ffn"].astype(jnp.float32)).astype(y.dtype) * y
     x = x + y
@@ -487,6 +490,13 @@ def _paged_scan(params, x, pools, cfg, positions, block_tables, seq_lens,
     # writes its K/V at [l, blk, off] in place. As scan xs / stacked ys
     # they would be sliced per layer and written into a fresh pool-sized
     # output, which donation cannot alias: a copy of the pools per program.
+    # The FFN's stacked weights stay out of the xs too, closed over and
+    # read-only: the TwELL kernels take them whole with the layer index and
+    # read that layer's tiles in place. As xs, each layer's matrices would
+    # first be copied out of the stack for the opaque kernel calls.
+    blocks = dict(params["blocks"])
+    ffn = blocks.pop("ffn", None)
+
     def body(carry, xs):
         xc, kp, vp = carry
         p, layer = xs
@@ -497,7 +507,8 @@ def _paged_scan(params, x, pools, cfg, positions, block_tables, seq_lens,
         if write_valid is not None:
             lc["write_valid"] = write_valid
         xc, aux, nc = _block_apply(p, xc, cfg, positions, kind="causal",
-                                   use_moe=fam == "moe", cache=lc)
+                                   use_moe=fam == "moe", cache=lc,
+                                   ffn=None if ffn is None else (ffn, layer))
         # two scalars per layer; cheap enough to ship every probed step
         ys = {"nnz_mean": aux["nnz_mean"], "tile_frac": aux["tile_frac"],
               "ffn_present": aux["ffn_present"]} if collect_aux else None
@@ -505,7 +516,7 @@ def _paged_scan(params, x, pools, cfg, positions, block_tables, seq_lens,
 
     (x, kps, vps), aux_stack = jax.lax.scan(
         body, (x, pools["kpool"], pools["vpool"]),
-        (params["blocks"], jnp.arange(num_layers, dtype=jnp.int32)))
+        (blocks, jnp.arange(num_layers, dtype=jnp.int32)))
     if last_rows is not None:
         # keep only each row's last valid hidden state before the O(V) head:
         # the engine samples one token per request, so materializing

@@ -1,5 +1,10 @@
 """Per-kernel allclose sweeps: every Pallas kernel (interpret mode) against
-its ref.py pure-jnp oracle across shapes and dtypes."""
+its ref.py pure-jnp oracle across shapes and dtypes.
+
+The TwELL FFN kernels take their weights stacked over layers with a layer
+index, as the serving scan hands them over; each is checked on the first,
+a middle and the last layer of a stack whose layers all differ, against the
+oracle on that layer's matrices."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -31,15 +36,37 @@ def _inputs(m, k, n, dtype, seed=0, sparse_shift=0.0, keep=0.3):
     return x, wg, wu, wd
 
 
+LAYERS = 3                  # a stack of three: first, middle, last layer
+
+
+def _stacked(m, k, n, dtype, layer, **kw):
+    """x and the stacked (LAYERS, ...) wg, wu, wd, every layer drawn from
+    its own seed, and ``layer``'s own matrices for the oracle."""
+    per = [_inputs(m, k, n, dtype, seed=i, **kw) for i in range(LAYERS)]
+    x = per[layer][0]
+    stacks = [jnp.stack([p[i] for p in per]) for i in (1, 2, 3)]
+    return x, stacks, per[layer][1:]
+
+
 SHAPES = [(64, 128, 256, 128, 4), (128, 256, 512, 256, 8),
           (64, 64, 1024, 256, 8), (256, 128, 256, 128, 8)]
+# the benchmark cells' FFN geometry at a reduced K: 22 (paper-1.5b) and 32
+# (olmo-1b) tiles of 256, at decode batches 1 and 8 and a 512-row prefill
+CELL_SHAPES = [(m, 128, nt * 256, 256, 8) for nt in (22, 32)
+               for m in (1, 8, 512)]
+# (shape, share of live gate columns) of the projection sweeps: the cells'
+# shapes at a share that keeps every tile within its slots, as the
+# benchmark's weights do
+PROJ_SHAPES = [(*s, 0.15) for s in SHAPES[:3]] + \
+    [(*s, 0.05) for s in CELL_SHAPES]
 
 
-@pytest.mark.parametrize("m,k,n,tile,c", SHAPES)
+@pytest.mark.parametrize("layer", range(LAYERS))
+@pytest.mark.parametrize("m,k,n,tile,c", SHAPES + CELL_SHAPES)
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_twell_pack_sweep(m, k, n, tile, c, dtype):
-    x, wg, _, _ = _inputs(m, k, n, dtype)
-    vals, idx, nnz = twell_gate_matmul_pallas(x, wg, tile, c, "relu",
+def test_twell_pack_sweep(m, k, n, tile, c, dtype, layer):
+    x, (wgs, _, _), (wg, _, _) = _stacked(m, k, n, dtype, layer)
+    vals, idx, nnz = twell_gate_matmul_pallas(x, wgs, layer, tile, c, "relu",
                                               bm=64, bk=64)
     tw = ref.twell_gate_matmul(x, wg, tile, c, "relu")
     np.testing.assert_array_equal(np.minimum(np.asarray(nnz), tile // c),
@@ -50,36 +77,44 @@ def test_twell_pack_sweep(m, k, n, tile, c, dtype):
     np.testing.assert_array_equal(np.asarray(idx), np.asarray(tw.indices))
 
 
-@pytest.mark.parametrize("m,k,n,tile,c", SHAPES[:3])
+@pytest.mark.parametrize("layer", range(LAYERS))
+@pytest.mark.parametrize("m,k,n,tile,c,keep", PROJ_SHAPES)
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_twell_fused_ffn_sweep(m, k, n, tile, c, dtype):
-    x, wg, wu, wd = _inputs(m, k, n, dtype, keep=0.15)
+def test_twell_fused_ffn_sweep(m, k, n, tile, c, keep, dtype, layer):
+    x, (_, wus, wds), (wg, wu, wd) = _stacked(m, k, n, dtype, layer,
+                                              keep=keep)
     tw = ref.twell_gate_matmul(x, wg, tile, c, "relu")
     if bool(tw.overflow):
         pytest.skip("overflowing geometry")
-    y = twell_fused_ffn_pallas(tw.values, tw.indices, tw.nnz, x, wu, wd,
-                               tile, bm=64)
+    y = twell_fused_ffn_pallas(tw.values, tw.indices, tw.nnz, x, wus, wds,
+                               layer, tile, bm=64)
     y_ref = ref.twell_fused_ffn(x, tw, wu, wd)
     np.testing.assert_allclose(np.asarray(y, np.float32),
                                np.asarray(y_ref, np.float32), **_tol(dtype))
 
 
-@pytest.mark.parametrize("m,k,n,tile,c", SHAPES[:2])
-def test_twell_down_proj_sweep(m, k, n, tile, c):
-    x, _, wu, wd = _inputs(m, k, n, jnp.float32, keep=0.15)
-    tw = ref.twell_gate_matmul(x, wu * 0.5 - 0.01, tile, c, "relu")
+@pytest.mark.parametrize("layer", range(LAYERS))
+@pytest.mark.parametrize("m,k,n,tile,c,keep", PROJ_SHAPES)
+def test_twell_down_proj_sweep(m, k, n, tile, c, keep, layer):
+    # the non-gated FFN's up projection packs: give it the live-column
+    # matrix, as the non-gated benchmark weights gate W_u's columns
+    x, (_, _, wds), (wu, _, wd) = _stacked(m, k, n, jnp.float32, layer,
+                                           keep=keep)
+    tw = ref.twell_gate_matmul(x, wu, tile, c, "relu")
     if bool(tw.overflow):
         pytest.skip("overflowing geometry")
-    y = twell_down_proj_pallas(tw.values, tw.indices, tw.nnz, wd, tile, bm=64)
+    y = twell_down_proj_pallas(tw.values, tw.indices, tw.nnz, wds, layer,
+                               tile, bm=64)
     np.testing.assert_allclose(y, ref.twell_down_proj(tw, wd),
                                rtol=2e-4, atol=2e-4)
 
 
+@pytest.mark.parametrize("layer", range(LAYERS))
 @pytest.mark.parametrize("m,k,n,tile,c", SHAPES[:2])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_tile_skip_ffn_sweep(m, k, n, tile, c, dtype):
-    x, wg, wu, wd = _inputs(m, k, n, dtype, keep=0.15)
-    y, h = tile_skip_ffn_pallas(x, wg, wu, wd, tile, "relu", bm=64)
+def test_tile_skip_ffn_sweep(m, k, n, tile, c, dtype, layer):
+    x, stacks, (wg, wu, wd) = _stacked(m, k, n, dtype, layer, keep=0.15)
+    y, h = tile_skip_ffn_pallas(x, *stacks, layer, tile, "relu", bm=64)
     y_ref, h_ref = ref.tile_skip_ffn(x, wg, wu, wd, tile, "relu")
     np.testing.assert_allclose(np.asarray(y, np.float32),
                                np.asarray(y_ref, np.float32), **_tol(dtype))

@@ -30,7 +30,9 @@ def _setup(gated=True, act="relu", keep_frac=0.25):
 
 
 def _dense_ref_loss(params, x, scfg, gated, l1c):
-    y, aux = sparse_ffn._dense_apply(params, x, scfg, gated)
+    y, aux = sparse_ffn.apply(params, x,
+                              dataclasses.replace(scfg, ffn_impl="dense"),
+                              gated)
     return (y ** 2).mean() + l1c * aux["l1"]
 
 

@@ -1,6 +1,7 @@
 """The five serving kernels compile for a described TPU v5e at paper-0.5b
-widths, and the whole paper-1.5b decode program holds no copy of its KV
-pools.
+widths, the whole paper-1.5b decode program holds no copy of its KV
+pools, and its FFN kernels read their layer's weights out of the stacked
+arrays.
 
 No chip is needed: the TPU compiler is installed, and it compiles for a
 topology that is described rather than attached. That catches what Pallas
@@ -18,6 +19,7 @@ is off around the compiles (an entry compiled for a described chip cannot
 be read back without one).
 """
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -25,6 +27,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
+from repro.kernels import ops
 from repro.models import lm
 from repro.kernels.paged_chunk_attention import paged_chunk_attention_pallas
 from repro.kernels.paged_decode_attention import paged_decode_attention_pallas
@@ -39,6 +42,7 @@ T, C = CFG.sparsity.twell_tile, CFG.sparsity.twell_c
 NT, TC = N // T, T // C
 H, HKV, HD = CFG.num_heads, CFG.num_kv_heads, CFG.resolved_head_dim
 BLOCK, WIDTH = 16, 32                 # KV block; table width of 512 tokens
+STACK = 4                             # FFN weights stacked over 4 layers
 FFN_M = [1, 8, 512]                   # decode buckets 1 and 8; 64 x 8 prefill
 ATTN_B = [1, 8]
 
@@ -78,37 +82,40 @@ def _packed(m):
     return [((m, NT * TC), BF16), ((m, NT * TC), I32), ((m, NT), I32)]
 
 
+# stacked (L, K, N) / (L, N, K) FFN weights and the layer to read
+W_KN, W_NK, LAYER = ((STACK, K, N), BF16), ((STACK, N, K), BF16), ((), I32)
+
+
 @pytest.mark.parametrize("m", FFN_M)
 def test_twell_gate_matmul_compiles(one_chip, m):
     _compile(one_chip,
-             lambda x, w: twell_gate_matmul_pallas(x, w, T, C, "relu",
-                                                   interpret=False),
-             ((m, K), BF16), ((K, N), BF16))
+             lambda x, w, layer: twell_gate_matmul_pallas(
+                 x, w, layer, T, C, "relu", interpret=False),
+             ((m, K), BF16), W_KN, LAYER)
 
 
 @pytest.mark.parametrize("m", FFN_M)
 def test_twell_fused_ffn_compiles(one_chip, m):
     _compile(one_chip,
-             lambda v, i, n, x, wu, wd: twell_fused_ffn_pallas(
-                 v, i, n, x, wu, wd, T, interpret=False),
-             *_packed(m), ((m, K), BF16), ((K, N), BF16), ((N, K), BF16))
+             lambda v, i, n, x, wu, wd, layer: twell_fused_ffn_pallas(
+                 v, i, n, x, wu, wd, layer, T, interpret=False),
+             *_packed(m), ((m, K), BF16), W_KN, W_NK, LAYER)
 
 
 @pytest.mark.parametrize("m", FFN_M)
 def test_twell_down_proj_compiles(one_chip, m):
     _compile(one_chip,
-             lambda v, i, n, wd: twell_down_proj_pallas(v, i, n, wd, T,
-                                                        interpret=False),
-             *_packed(m), ((N, K), BF16))
+             lambda v, i, n, wd, layer: twell_down_proj_pallas(
+                 v, i, n, wd, layer, T, interpret=False),
+             *_packed(m), W_NK, LAYER)
 
 
 @pytest.mark.parametrize("m", FFN_M)
 def test_tile_skip_ffn_compiles(one_chip, m):
     _compile(one_chip,
-             lambda x, wg, wu, wd: tile_skip_ffn_pallas(x, wg, wu, wd, T,
-                                                        "relu",
-                                                        interpret=False),
-             ((m, K), BF16), ((K, N), BF16), ((K, N), BF16), ((N, K), BF16))
+             lambda x, wg, wu, wd, layer: tile_skip_ffn_pallas(
+                 x, wg, wu, wd, layer, T, "relu", interpret=False),
+             ((m, K), BF16), W_KN, W_KN, W_NK, LAYER)
 
 
 def _pools(b):
@@ -134,16 +141,10 @@ def test_paged_chunk_attention_compiles(one_chip, b):
              ((b, 64, H, HD), BF16), *_pools(b), ((b,), I32), ((), I32))
 
 
-@pytest.mark.parametrize("phase", ["decode", "prefill"])
-def test_paper_1_5b_step_holds_no_pool_copy(one_chip, phase):
-    """paper-1.5b served as its benchmark cell serves it: batch 16, 256
-    blocks of 64 (3.5 GiB of bf16 pools), a table of 1920 tokens, a
-    256-token prefill chunk, pools donated. Taking the pools as scan inputs
-    and stacking them back held 4.0 GiB of temporaries in the decode
-    program, relaying the pools out for the kernel layer by layer. The
-    prefill program at 16 x 256 is the largest bucket the engine's warmup
-    compiles. The FFN takes the host's path here; the attention kernels are
-    the chip's."""
+def _paper_1_5b_step(sharding, phase):
+    """paper-1.5b's decode or prefill program compiled as its benchmark cell
+    serves it: batch 16, 256 blocks of 64 (3.5 GiB of bf16 pools), a table
+    of 1920 tokens, a 256-token prefill chunk, pools donated."""
     from repro.serving.backends import get_backend
     cfg = get_backend("gather").configure(
         dataclasses.replace(get_config("paper-1.5b"), attn_backend="pallas"),
@@ -151,10 +152,10 @@ def test_paper_1_5b_step_holds_no_pool_copy(one_chip, phase):
     b, blocks, block, width, chunk = 16, 256, 64, 1920 // 64, 256
 
     def on_chip(x):
-        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
 
     def i32(*shape):
-        return jax.ShapeDtypeStruct(shape, I32, sharding=one_chip)
+        return jax.ShapeDtypeStruct(shape, I32, sharding=sharding)
 
     params = jax.tree.map(on_chip, jax.eval_shape(
         lambda: lm.init(jax.random.PRNGKey(0), cfg)))
@@ -171,5 +172,39 @@ def test_paper_1_5b_step_holds_no_pool_copy(one_chip, phase):
         args = (i32(b, width), i32(b, chunk), i32(b), i32(b))
     compiled = step.lower(params, pools, *args).compile()
     assert 'custom_call_target="tpu_custom_call"' in compiled.as_text()
+    return cfg, compiled
+
+
+@pytest.mark.parametrize("phase", ["decode", "prefill"])
+def test_paper_1_5b_step_holds_no_pool_copy(one_chip, phase):
+    """Taking the pools as scan inputs and stacking them back held 4.0 GiB
+    of temporaries in the decode program, relaying the pools out for the
+    kernel layer by layer. The prefill program at 16 x 256 is the largest
+    bucket the engine's warmup compiles. The FFN takes the host's path
+    here; the attention kernels are the chip's."""
+    _, compiled = _paper_1_5b_step(one_chip, phase)
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < 0.5 * 2 ** 30, f"temporaries {temp / 2 ** 30:.2f} GiB"
+
+
+@pytest.mark.parametrize("phase", ["decode", "prefill"])
+def test_paper_1_5b_step_reads_ffn_weights_in_place(one_chip, monkeypatch,
+                                                    phase):
+    """With the FFN on the chip's path (the TwELL kernels), no value in the
+    program is one layer's FFN matrix: the kernels take the stacked
+    (L, d, d_ff) / (L, d_ff, d) weights whole. Scanned as layer inputs, each
+    layer's three matrices were copied out of the stack (into VMEM) before
+    the opaque kernel calls, a ``dynamic-slice`` per matrix per layer."""
+    monkeypatch.setattr(ops, "kernel_mode", lambda: "pallas")
+    cfg, compiled = _paper_1_5b_step(one_chip, phase)
+    text = compiled.as_text()
+    for kernel in ("twell_gate_matmul", "twell_fused_ffn"):
+        assert kernel in text
+    d, f, layers = cfg.d_model, cfg.d_ff, cfg.num_layers
+    stacked = [f"bf16[{layers},{d},{f}]", f"bf16[{layers},{f},{d}]"]
+    assert all(s in text for s in stacked)
+    # a value of one layer's shape, with or without its unit layer axis
+    one_layer = re.compile(rf"= bf16\[(1,)?({d},{f}|{f},{d})\]")
+    copies = [ln.strip()[:160] for ln in text.splitlines()
+              if one_layer.search(ln)]
+    assert not copies, copies
